@@ -19,6 +19,8 @@ def main(argv=None) -> None:
                     help="comma-separated benchmark names to run")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import (common, fig03_footprint, fig07_single_core,
                             fig08_eight_core, fig09_cache_hit,
                             fig10_row_hit, fig11_energy, fig12_capacity,
@@ -65,7 +67,7 @@ def main(argv=None) -> None:
          lambda s: s.get("fts_kB_per_channel")),
     ]
     only = {n for n in args.only.split(",") if n}
-    known = {n for n, _, _ in benches} | {"roofline"}
+    known = {n for n, _, _ in benches}
     unknown = only - known
     if unknown:
         ap.error(f"unknown benchmark(s) {sorted(unknown)}; "
@@ -80,17 +82,6 @@ def main(argv=None) -> None:
         us = (time.time() - t0) * 1e6
         print(f"{name},{us:.0f},{pick(summary)}", flush=True)
         details[name] = summary
-    # roofline table is read from dry-run artifacts (no compute)
-    if not only or "roofline" in only:
-        try:
-            from benchmarks import roofline
-            t0 = time.time()
-            rows, summary = roofline.run()
-            us = (time.time() - t0) * 1e6
-            print(f"roofline,{us:.0f},{summary['mean_roofline_frac']}")
-            details["roofline"] = summary
-        except Exception as e:  # dry-run not yet executed
-            print(f"roofline,0,unavailable({e})")
     print("\n# summaries", file=sys.stderr)
     for k, v in details.items():
         print(k, v, file=sys.stderr)

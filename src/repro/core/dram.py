@@ -55,7 +55,6 @@ from repro.core import fts as fts_lib
 from repro.core.timing import (DDR4, GEOM, DRAMGeometry, DRAMTimings,
                                MechConfig, MechParams, StaticConfig)
 from repro.kernels.fts_lookup.ops import fts_lookup_op
-from repro.kernels.jax_compat import is_tracer
 
 
 class Trace(NamedTuple):
@@ -1073,7 +1072,7 @@ def resume(trace: Trace, static: StaticConfig, params: MechParams,
     leaves are (C, T).  The jitted form is ``run_segment``: every chunk
     of the same shape reuses ONE compiled step (the fixed-shape chunks of
     the ``traces`` codec are built for exactly this)."""
-    if is_tracer(trace.t_issue):
+    if isinstance(trace.t_issue, jax.core.Tracer):
         _note_trace(f"segment/{static.mechanism}/{variant}")
     return _resume(trace, static, params, state, variant)[0]
 
@@ -1087,7 +1086,7 @@ def resume_tel(trace: Trace, static: StaticConfig, params: MechParams,
     if static.telemetry <= 0:
         raise ValueError("resume_tel needs StaticConfig.telemetry > 0 "
                          "(the window period in real requests)")
-    if is_tracer(trace.t_issue):
+    if isinstance(trace.t_issue, jax.core.Tracer):
         _note_trace(f"segment_tel/{static.mechanism}/{variant}")
     return _resume(trace, static, params, state, variant)
 
@@ -1105,7 +1104,7 @@ def simulate(trace: Trace, static: StaticConfig, params: MechParams,
     Literally ``finalize(resume(trace, ..., sim_init(...)))`` — the
     monolithic scan IS the one-chunk case of the segment API, which is
     what makes chunk-size invariance structural rather than asserted."""
-    if is_tracer(trace.t_issue):
+    if isinstance(trace.t_issue, jax.core.Tracer):
         # log only when called under a jit trace (== one compilation);
         # eager reference runs must not inflate the jit count
         _note_trace(f"simulate/{static.mechanism}/{variant}")
@@ -1140,7 +1139,7 @@ def sweep_resume(trace: Trace, static: StaticConfig,
     """Un-jitted batched segment: ``run_sweep``'s one-chunk body, resumed
     from ``state`` (leading (P,) axes from ``sim_init(..., batch=P)``).
     The jitted form is ``run_sweep_segment``."""
-    if is_tracer(trace.t_issue):
+    if isinstance(trace.t_issue, jax.core.Tracer):
         _note_trace(f"sweep_segment/{static.mechanism}/{variant}")
     return _sweep_resume(trace, static, params_batch, state, variant)[0]
 
@@ -1154,7 +1153,7 @@ def sweep_resume_tel(trace: Trace, static: StaticConfig,
     if static.telemetry <= 0:
         raise ValueError("sweep_resume_tel needs StaticConfig.telemetry > 0 "
                          "(the window period in real requests)")
-    if is_tracer(trace.t_issue):
+    if isinstance(trace.t_issue, jax.core.Tracer):
         _note_trace(f"sweep_segment_tel/{static.mechanism}/{variant}")
     return _sweep_resume(trace, static, params_batch, state, variant)
 

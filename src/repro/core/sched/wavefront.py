@@ -61,7 +61,6 @@ from repro.core import dram
 from repro.core import fts as fts_lib
 from repro.core.timing import (DDR4, GEOM, DRAMGeometry, DRAMTimings,
                                MechConfig, MechParams, StaticConfig)
-from repro.kernels.jax_compat import is_tracer
 
 __all__ = ["form_waves", "linearize_waves", "wave_stats", "make_wave_step",
            "pad_waves", "resume_waves", "run_segment_waves",
@@ -421,7 +420,7 @@ def resume_waves(wtrace: dram.Trace, static: StaticConfig,
     lane, so counters stay bitwise-equal to the monolithic serial scan
     regardless (``tests/test_streaming.py``).  Jitted form:
     ``run_segment_waves``."""
-    if is_tracer(wtrace.t_issue):
+    if isinstance(wtrace.t_issue, jax.core.Tracer):
         dram._note_trace(f"wave_segment/{static.mechanism}")
     return _resume_waves(wtrace, static, params, state)
 
@@ -433,7 +432,7 @@ def simulate_waves(wtrace: dram.Trace, static: StaticConfig,
                    params: MechParams) -> dram.Counters:
     """Un-jitted reference over a wave-compiled trace: (n_waves, W) or
     (C, n_waves, W) leaves, one params point."""
-    if is_tracer(wtrace.t_issue):
+    if isinstance(wtrace.t_issue, jax.core.Tracer):
         dram._note_trace(f"wave/{static.mechanism}")
     C = wtrace.t_issue.shape[0] if wtrace.t_issue.ndim == 3 else None
     state = dram.sim_init(static, channels=C)

@@ -14,8 +14,6 @@ grid = (n_moves,); every step copies one segment.  In-place aliasing
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -43,26 +41,26 @@ def reloc(pool: jax.Array, fast: jax.Array, src_segs: jax.Array,
     Returns the updated fast pool (aliased with the input).
     """
     n_moves = src_segs.shape[0]
-    E = pool.shape[1]
+    n_slots, E = fast.shape
     # scalar-prefetch carries both address streams (RELOC's two column addrs)
     ids = jnp.concatenate([src_segs, dst_slots]).astype(jnp.int32)
+    # one segment per block: a unit sublane axis lets the (1, E) block span
+    # the last two dims, which Mosaic requires of blocks not (8, 128)-tiled
+    src = pl.BlockSpec((None, 1, E),
+                       lambda i, ids: (jnp.maximum(ids[i], 0), 0, 0))
+    dst = pl.BlockSpec((None, 1, E), lambda i, ids: (ids[n_moves + i], 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_moves,),
-        in_specs=[
-            pl.BlockSpec((1, E),
-                         lambda i, ids: (jnp.maximum(ids[i], 0), 0)),
-            pl.BlockSpec((1, E),
-                         lambda i, ids: (ids[n_moves + i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, E),
-                               lambda i, ids: (ids[n_moves + i], 0)),
+        in_specs=[src, dst],
+        out_specs=dst,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(fast.shape, fast.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_slots, 1, E), fast.dtype),
         input_output_aliases={2: 0},   # fast buffer updated in place
         interpret=interpret,
-    )(ids, pool, fast)
+    )(ids, pool.reshape(pool.shape[0], 1, E), fast.reshape(n_slots, 1, E))
+    return out.reshape(n_slots, E)

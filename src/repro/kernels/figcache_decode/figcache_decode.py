@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
-
 NEG = -1e30
 
 
@@ -33,26 +31,26 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...].astype(jnp.float32)          # (1, D) block
-    k = k_ref[0].astype(jnp.float32)            # (bl, D)
-    v = v_ref[0].astype(jnp.float32)
-    ok = valid_ref[0]                           # (bl,)
+    q = q_ref[...].astype(jnp.float32)          # (1, D)
+    k = k_ref[...].astype(jnp.float32)          # (bl, D)
+    v = v_ref[...].astype(jnp.float32)
+    ok = valid_ref[...] != 0                    # (1, bl)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)[0]
+                            preferred_element_type=jnp.float32)
     s = s * (q.shape[-1] ** -0.5)
-    s = jnp.where(ok, s, NEG)
+    s = jnp.where(ok, s, NEG)                   # (1, bl)
 
-    m_prev = m_ref[0]
-    m_new = jnp.maximum(m_prev, s.max())
+    m_prev = m_ref[...]                         # (1, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[0] = l_ref[0] * corr + p.sum()
-    acc_ref[...] = acc_ref[...] * corr + (p[None, :] @ v)
-    m_ref[0] = m_new
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + p @ v
+    m_ref[...] = m_new
 
     @pl.when(li == n_l - 1)
     def _finish():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[0], 1e-30)
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                       ).astype(o_ref.dtype)
 
 
@@ -60,29 +58,33 @@ def figcache_decode(q, k, v, valid, *, heads_per_seq: int,
                     block_l: int = 256, interpret: bool = False):
     """q (BH, D); k/v (BH, L, D); valid (B, L); BH = B * heads_per_seq."""
     BH, D = q.shape
-    L = k.shape[1]
+    B, L = valid.shape
     block_l = min(block_l, L)
     assert L % block_l == 0
     n_l = L // block_l
     H = heads_per_seq
     kern = functools.partial(_kernel, n_l=n_l)
-    return pl.pallas_call(
+    # q/out rows and mask rows get a unit sublane axis so each (1, D) or
+    # (1, block_l) block spans the last two dims, as Mosaic requires of
+    # blocks that are not (8, 128)-tiled; the row axis itself is squeezed
+    row = pl.BlockSpec((None, 1, D), lambda b, j: (b, 0, 0))
+    kv = pl.BlockSpec((None, block_l, D), lambda b, j: (b, j, 0))
+    out = pl.pallas_call(
         kern,
         grid=(BH, n_l),
         in_specs=[
-            pl.BlockSpec((1, D), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, block_l, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_l, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_l), lambda b, j: (b // H, j)),
+            row, kv, kv,
+            pl.BlockSpec((None, 1, block_l), lambda b, j: (b // H, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, D), lambda b, j: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, D), q.dtype),
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((BH, 1, D), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, valid)
+    )(q.reshape(BH, 1, D), k, v, valid.astype(jnp.int32).reshape(B, 1, L))
+    return out.reshape(BH, D)

@@ -57,15 +57,17 @@ def fts_lookup(tags: jax.Array, score: jax.Array, bank: jax.Array,
     (``n_slots`` active slots, or the live-row count when ``score`` is the
     RowBenefit per-row sum); ``limit <= 0`` yields candidate 0.
     """
-    n_slots = tags.shape[1]
+    n_banks, n_slots = tags.shape
     ids = jnp.stack([bank, seg, limit]).astype(jnp.int32)
+    # Mosaic tiles the last two block dims by (8, 128) unless they span the
+    # whole array, so a (1, n_slots) row block of the (n_banks, n_slots)
+    # table is refused.  A unit sublane axis makes the row block span its
+    # last two dims; the bank axis is squeezed and picked by the prefetch.
+    row = pl.BlockSpec((None, 1, n_slots), lambda i, ids: (ids[0], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
-        in_specs=[
-            pl.BlockSpec((1, n_slots), lambda i, ids: (ids[0], 0)),
-            pl.BlockSpec((1, n_slots), lambda i, ids: (ids[0], 0)),
-        ],
+        in_specs=[row, row],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
     )
     return pl.pallas_call(
@@ -73,4 +75,5 @@ def fts_lookup(tags: jax.Array, score: jax.Array, bank: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((3,), jnp.int32),
         interpret=interpret,
-    )(ids, tags, score)
+    )(ids, tags.reshape(n_banks, 1, n_slots),
+      score.reshape(n_banks, 1, n_slots))

@@ -1,4 +1,4 @@
-"""Production mesh construction (multi-pod dry-run contract).
+"""Mesh construction for the model steps and the sharded sweep.
 
 A FUNCTION, not a module-level constant: importing this module never touches
 jax device state.
@@ -8,25 +8,13 @@ from __future__ import annotations
 import jax
 
 
-def set_mesh(mesh):
-    """Compat wrapper for ``jax.set_mesh`` (added after 0.4.x).
-
-    On newer JAX it installs the mesh for sharding-in-types; on older
-    releases a ``Mesh`` is itself the equivalent context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
 def make_test_mesh(dp: int = 1, tp: int = 1):
-    """Small mesh over however many devices the test environment has."""
-    return jax.make_mesh((dp, tp), ("data", "model"))
+    """Small mesh over however many devices the test environment has.
+
+    Its axes are Auto: the model steps shard by ``with_sharding_constraint``,
+    which asserts under the Explicit axes ``jax.make_mesh`` defaults to."""
+    auto = jax.sharding.AxisType.Auto
+    return jax.make_mesh((dp, tp), ("data", "model"), axis_types=(auto, auto))
 
 
 def make_sweep_mesh(n_params: int, n_channels: int, devices=None):

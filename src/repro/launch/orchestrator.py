@@ -72,6 +72,7 @@ from repro.core.sched import policies as sched_policies
 from repro.core.timing import (DDR4, DRAMTimings, MechConfig, SchedConfig,
                                paper_config, shared_static)
 from repro.core.workload import content_hash
+from repro.launch import compile_cache
 from repro.launch.mesh import make_sweep_mesh
 from repro.obs.trace import Tracer, chrome_from_jsonl
 from repro.runtime.fault_tolerance import HeartbeatMonitor
@@ -386,6 +387,10 @@ class Orchestrator:
                                                  self._ckpt_dir(shard.key))
                 e["segments_done"] = i + 1
                 write_manifest(self.manifest_path, self.manifest)
+        # the devices that held the shard's final carry, read off its
+        # sharding: all of the mesh when placement spread the work
+        e["devices"] = sorted(
+            d.id for d in prog.sim.cnt.reads.sharding.device_set)
         cnts = jax.tree.map(lambda a: np.array(jax.device_get(a)),
                             dram.finalize(prog.sim))
         quarantined = self._apply_poison_and_diagnose(shard_idx, shard, cnts)
@@ -643,6 +648,7 @@ def main(argv=None) -> int:
     cmpp.add_argument("--chunk-len", type=int, default=128)
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     plan = ci_grid(args.chunk_len)
     if args.cmd == "run":
         fault_plan = FaultPlan()

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
-from repro.launch.mesh import make_test_mesh
+from repro.launch import compile_cache
 from repro.models import build_model, Plan
 from repro.figkv import figkv_init, figkv_prefill, figkv_decode_step
 
@@ -96,12 +96,14 @@ def demo_figkv(cfg, rng, prompt_len, gen, batch):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--figkv", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
     run(args.arch, reduced=args.reduced, prompt_len=args.prompt_len,
         gen=args.gen, batch=args.batch, figkv=args.figkv)
 

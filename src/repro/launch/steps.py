@@ -1,6 +1,6 @@
 """Step builders: plan selection, input specs, jitted train/prefill/decode
-functions with full sharding contracts.  Shared by the dry-run, the training
-driver, and the serving driver.
+functions with full sharding contracts.  Shared by ``launch/train.py`` and
+the tests.
 """
 from __future__ import annotations
 

@@ -4,8 +4,9 @@ checkpoint/restart, async checkpointing.
     PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-0.5b \
         --shape train_4k --steps 50 --reduced --ckpt /tmp/ckpt
 
-``--reduced`` runs the small same-family config on CPU (the e2e example path);
-the full configs are exercised via the dry-run.
+``--reduced`` (the default) runs the small same-family config, the e2e
+example path; ``--no-reduced`` asks for the published config, which needs a
+real cluster.
 """
 from __future__ import annotations
 
@@ -20,8 +21,7 @@ from repro import configs
 from repro.checkpoint import AsyncCheckpointer, latest_step, restore_checkpoint
 from repro.data import DataPipeline
 from repro.launch import sharding as shd
-from repro.launch import steps as steps_lib
-from repro.launch import mesh as mesh_lib
+from repro.launch import compile_cache, steps as steps_lib
 from repro.launch.mesh import make_test_mesh
 from repro.models import build_model
 from repro.runtime import HeartbeatMonitor, StepRunner
@@ -46,7 +46,7 @@ def run(arch: str, shape_name: str, *, steps: int = 50, reduced: bool = True,
                                overrides={"microbatches": 1, "remat": "full"})
     model = build_model(cfg, plan)
 
-    with mesh_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn, state_sh = steps_lib.make_train_step(model, mesh, hyper)
         start = 0
         pipe = DataPipeline(cfg, shape, seed=0)
@@ -85,12 +85,14 @@ def main():
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", default="train_4k")
     ap.add_argument("--steps", type=int, default=50)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True)
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--grad-compress", action="store_true")
     ap.add_argument("--batch", type=int, default=None)
     ap.add_argument("--seq", type=int, default=None)
     args = ap.parse_args()
+    compile_cache.enable()
     t0 = time.time()
     losses = run(args.arch, args.shape, steps=args.steps,
                  reduced=args.reduced, ckpt_dir=args.ckpt,

@@ -228,8 +228,8 @@ def stack_forward(params, x: jax.Array, cfg: ModelConfig, plan: Plan, *,
                     (x, aux_total), gparams)
             new_caches.append(ncs)
         else:
-            # unrolled (dry-run analysis mode: exact per-layer HLO cost;
-            # XLA counts while-loop bodies once — see launch/analysis.py)
+            # unrolled: XLA's cost analysis counts a while-loop body
+            # once, so only an unrolled stack gives exact per-layer cost
             ncs_list = []
             for i in range(count):
                 bp = jax.tree.map(lambda a: a[i], gparams)

@@ -187,3 +187,39 @@ def test_cache_shardings_typed():
     sh = cache_shardings(caches, mesh)
     # structure must match exactly (tree prefix errors would throw in jit)
     jax.tree.map(lambda a, b: None, caches, sh)
+
+
+# ---------------- persistent compile cache ----------------
+
+def test_compile_cache_defaults_to_checkout(monkeypatch):
+    from repro.launch import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    try:
+        path = compile_cache.enable()
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_env_dir_is_used(tmp_path):
+    """A set JAX_COMPILATION_CACHE_DIR is left alone, and the compile lands
+    there (a fresh process: the cache is set up at the first compile)."""
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.launch import compile_cache\n"
+            "print(compile_cache.enable())\n"
+            "jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()\n")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PLATFORMS="cpu", PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == str(tmp_path)
+    assert os.listdir(tmp_path)

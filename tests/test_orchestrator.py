@@ -17,6 +17,7 @@ import pytest
 from repro.core import simulator, workload
 from repro.core.timing import paper_config
 from repro.launch import orchestrator as orch_mod
+from repro.launch.mesh import make_sweep_mesh
 from repro.runtime.faults import FaultEvent, FaultPlan, InjectedKill
 
 CHUNK = 128
@@ -52,6 +53,18 @@ def test_uninterrupted_matches_sweep_traces_oracle(plan, oracle):
     for (w, i), cnt in oracle.items():
         for name, a, b in zip(type(cnt)._fields, cnt, ref[w][i].counters):
             assert np.array_equal(np.asarray(a), np.asarray(b)), (w, i, name)
+
+
+def test_manifest_records_mesh_devices(plan, tmp_path):
+    # each done shard names the devices its final carry sat on: the whole
+    # ("params", "channel") mesh that make_sweep_mesh lays it over
+    o = orch_mod.Orchestrator(plan, str(tmp_path), backoff_s=0.0)
+    assert o.run() == {"done": len(plan.shards)}
+    for shard in plan.shards:
+        mesh = make_sweep_mesh(len(shard.cfg_idxs),
+                               plan.specs[shard.w].n_channels)
+        want = sorted(d.id for d in mesh.devices.flat)
+        assert o.manifest["shards"][shard.key]["devices"] == want
 
 
 @pytest.mark.parametrize("segment", [0, 1, 2],

@@ -10,7 +10,6 @@ import pytest
 from repro import configs
 from repro.data import DataPipeline
 from repro.launch import steps as steps_lib
-from repro.launch import mesh as mesh_lib
 from repro.launch.mesh import make_test_mesh
 from repro.models import build_model
 
@@ -27,7 +26,7 @@ def test_train_loss_decreases():
     plan = steps_lib.make_plan(cfg, shape, mesh,
                                overrides={"microbatches": 1})
     model = build_model(cfg, plan)
-    with mesh_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step, _ = steps_lib.make_train_step(model, mesh, hyper)
         state = steps_lib.init_train_state(model, jax.random.PRNGKey(0), hyper)
         pipe = DataPipeline(cfg, shape, seed=0)
@@ -49,7 +48,7 @@ def test_checkpoint_restart_resumes_stream(tmp_path):
     plan = steps_lib.make_plan(cfg, shape, mesh,
                                overrides={"microbatches": 1})
     model = build_model(cfg, plan)
-    with mesh_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step, state_sh = steps_lib.make_train_step(model, mesh, hyper)
         state = steps_lib.init_train_state(model, jax.random.PRNGKey(1), hyper)
         pipe = DataPipeline(cfg, shape, seed=3)
@@ -91,7 +90,7 @@ def test_grad_compress_converges():
     plan = steps_lib.make_plan(cfg, shape, mesh,
                                overrides={"microbatches": 1})
     model = build_model(cfg, plan)
-    with mesh_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step, _ = steps_lib.make_train_step(model, mesh, hyper)
         state = steps_lib.init_train_state(model, jax.random.PRNGKey(0), hyper)
         pipe = DataPipeline(cfg, shape, seed=0)
@@ -116,7 +115,7 @@ def test_microbatched_step_matches_single():
         plan = steps_lib.make_plan(cfg, shape, mesh,
                                    overrides={"microbatches": mb})
         model = build_model(cfg, plan)
-        with mesh_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step, _ = steps_lib.make_train_step(model, mesh, hyper)
             state = steps_lib.init_train_state(model, jax.random.PRNGKey(7),
                                                hyper)
