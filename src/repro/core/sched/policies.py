@@ -42,11 +42,13 @@ benchmark layer, and the pass is O(T * queue_depth).
 """
 from __future__ import annotations
 
+import math
 from typing import List, Optional, Sequence
 
 import numpy as np
 
 from repro.core.dram import NOOP_ISSUE, Trace
+from repro.obs.trace import span
 from repro.core.timing import (GEOM, SCHED_FCFS, TICKS_PER_NS, DRAMGeometry,
                                SchedConfig)
 
@@ -141,7 +143,17 @@ def schedule(trace: Trace, sc: Optional[SchedConfig],
              geom: DRAMGeometry = GEOM) -> Trace:
     """Reorder a (T,) or (C, T) trace into the service order ``sc``'s
     controller would issue.  FCFS (or ``sc=None``) returns the trace
-    object untouched — the existing zero-controller behavior."""
+    object untouched — the existing zero-controller behavior.  Each call
+    is one ``repro.sched.schedule`` span (stats: ``policy``, ``requests``,
+    the trace's entries)."""
+    with span("repro.sched.schedule",
+              policy=SCHED_FCFS.policy if sc is None else sc.policy,
+              requests=math.prod(np.shape(trace.t_issue))):
+        return _schedule(trace, sc, geom)
+
+
+def _schedule(trace: Trace, sc: Optional[SchedConfig],
+              geom: DRAMGeometry) -> Trace:
     if sc is None or sc.is_identity:
         return trace
     t = np.asarray(trace.t_issue)

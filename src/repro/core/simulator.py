@@ -26,6 +26,14 @@ Python-side loop for the IPC/energy model.  ``run_single_core`` /
 ``run_single_core_batch`` / ``run_eight_core_batch`` are their stacked-trace
 counterparts (figs 7/8).
 
+Host-path spans (DESIGN.md §15): ``sweep`` and ``sweep_traces`` mark each
+phase of their host path with an ``obs.trace.span`` on the profiler's
+clock — ``repro.sched.schedule`` (in ``sched_policies.schedule``),
+``repro.sweep.stack`` (no-op padding and channel stacking, once per
+controller), ``repro.sweep.dispatch`` (once per static group) and
+``repro.sweep.post`` (once per group and workload) — siblings, disjoint
+in time, whose stats are counted from shapes and Python values.
+
 Workloads are first-class sweep axes too (DESIGN.md §11): ``sweep_traces``
 accepts ``workload.WorkloadSpec`` entries and synthesizes those traces on
 device (specs sharing a generator structure batch into one vmapped compiled
@@ -47,6 +55,7 @@ from repro.core.energy import ENERGY
 from repro.core.sched import policies as sched_policies
 from repro.core.timing import (DDR4, GEOM, DRAMTimings, MechConfig,
                                paper_config, shared_static, static_group_key)
+from repro.obs.trace import span
 
 CPU_GHZ = 3.2
 CPI_EXEC = 0.4          # 3-wide OoO issue
@@ -55,6 +64,9 @@ MLP_NON = 1.4
 
 PAPER_MECHS = ("base", "lisa_villa", "figcache_slow", "figcache_fast",
                "figcache_ideal", "lldram")
+
+# the counter leaves post-processing copies to the host, once each
+_HOST_LEAVES = tuple(f for f in dram.Counters._fields if f != "t_end")
 
 
 @dataclasses.dataclass
@@ -88,10 +100,13 @@ def _results_from_counters_batch(cnts, cfgs: Sequence[MechConfig],
     the MLP-weighted IPC model, execution time and the energy model all
     evaluate vectorized over that axis, so post-processing a large grid is a
     handful of numpy array ops instead of a Python loop (ROADMAP item).
+    Each leaf in ``_HOST_LEAVES`` is copied to the host once.
     """
     P = len(cfgs)
-    lat = np.asarray(cnts.lat_sum_ns, dtype=np.float64)  # (P, [C,] cores)
-    req = np.asarray(cnts.req_cnt, dtype=np.float64)
+    host = cnts._replace(
+        **{k: np.asarray(getattr(cnts, k)) for k in _HOST_LEAVES})
+    lat = np.asarray(host.lat_sum_ns, dtype=np.float64)  # (P, [C,] cores)
+    req = np.asarray(host.req_cnt, dtype=np.float64)
     if lat.ndim == 3:                # multi-channel: sum over channels
         lat, req = lat.sum(1), req.sum(1)
     avg_lat = np.where(req > 0, lat / np.maximum(req, 1), 0.0)
@@ -108,10 +123,10 @@ def _results_from_counters_batch(cnts, cfgs: Sequence[MechConfig],
     exec_ns = np.where(r > 0, cycles / CPU_GHZ, 0.0).max(axis=1)
     instr_tot = instr.sum(axis=1)
     tot = lambda x: np.asarray(x, dtype=np.float64).reshape(P, -1).sum(axis=1)
-    n_req = tot(cnts.reads) + tot(cnts.writes)
-    parts = ENERGY.system_energy_nj_batch(cnts, n_channels, n_apps,
+    n_req = tot(host.reads) + tot(host.writes)
+    parts = ENERGY.system_energy_nj_batch(host, n_channels, n_apps,
                                           instr_tot, exec_ns, tot)
-    row_hits, cache_hits = tot(cnts.row_hits), tot(cnts.cache_hits)
+    row_hits, cache_hits = tot(host.row_hits), tot(host.cache_hits)
     out = []
     for i, cfg in enumerate(cfgs):
         div = n_req[i] if n_req[i] else 1.0
@@ -128,6 +143,24 @@ def _results_from_counters_batch(cnts, cfgs: Sequence[MechConfig],
             counters=jax.tree.map(lambda a, i=i: a[i], cnts),
         ))
     return out
+
+
+def _post_stats(n_cfgs: int, slice_ops: int) -> dict:
+    """Stats of one ``repro.sweep.post`` span.  ``device_ops``: the device
+    programs its indexing launches — ``slice_ops`` per counter leaf to cut
+    one workload out, then per leaf and configuration an integer index,
+    which JAX runs as a dynamic slice and a squeeze; ``d2h_copies``: the
+    leaves ``_results_from_counters_batch`` copies to the host."""
+    n_leaves = len(dram.Counters._fields)
+    return {"configs": n_cfgs,
+            "device_ops": n_leaves * (slice_ops + 2 * n_cfgs),
+            "d2h_copies": len(_HOST_LEAVES)}
+
+
+def _stack_params(cfgs: Sequence[MechConfig], idxs: Sequence[int],
+                  t: DRAMTimings):
+    return jax.tree.map(lambda *xs: jnp.stack(xs),
+                        *[cfgs[i].params(t) for i in idxs])
 
 
 def _result_from_counters(cnt, cfg: MechConfig, apps: Sequence,
@@ -185,11 +218,14 @@ def sweep(trace: dram.Trace, cfgs: Sequence[MechConfig],
     for (static, sc), idxs in _static_groups(cfgs).items():
         if sc not in scheduled:
             scheduled[sc] = sched_policies.schedule(trace, sc)
-        batch = jax.tree.map(lambda *xs: jnp.stack(xs),
-                             *[cfgs[i].params(t) for i in idxs])
-        cnts = _dispatch_sweep(scheduled[sc], static, batch, chunk_len)
-        results = _results_from_counters_batch(
-            cnts, [cfgs[i] for i in idxs], apps, n_channels)
+        P = len(idxs)
+        with span("repro.sweep.dispatch", mechanism=static.mechanism,
+                  configs=P, lanes=P * n_channels):
+            cnts = _dispatch_sweep(scheduled[sc], static,
+                                   _stack_params(cfgs, idxs, t), chunk_len)
+        with span("repro.sweep.post", **_post_stats(P, 0)):
+            results = _results_from_counters_batch(
+                cnts, [cfgs[i] for i in idxs], apps, n_channels)
         for j, i in enumerate(idxs):
             out[i] = results[j]
     return out
@@ -281,38 +317,48 @@ def sweep_traces(trs: Sequence, cfgs: Sequence[MechConfig],
 
     def flat_for(sc):
         """Channel-stack the W workload traces under controller ``sc``
-        (scheduling precedes no-op padding so the no-op suffix invariant
-        holds); memoized per distinct controller."""
+        (all scheduled first, then no-op padded, so the no-op suffix
+        invariant holds and the stack span holds no schedule span);
+        memoized per distinct controller."""
         if sc not in stacked:
-            s_trs = [dram.noop_pad(sched_policies.schedule(tr, sc), t_max)
-                     for tr in trs]
-            if multi:
-                stacked[sc] = jax.tree.map(
-                    lambda *xs: jnp.concatenate(
-                        [jnp.asarray(x) for x in xs], axis=0), *s_trs)
-            else:
-                stacked[sc] = jax.tree.map(
-                    lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]),
-                    *s_trs)
+            s_trs = [sched_policies.schedule(tr, sc) for tr in trs]
+            with span("repro.sweep.stack", workloads=W, trips=t_max):
+                s_trs = [dram.noop_pad(tr, t_max) for tr in s_trs]
+                if multi:
+                    stacked[sc] = jax.tree.map(
+                        lambda *xs: jnp.concatenate(
+                            [jnp.asarray(x) for x in xs], axis=0), *s_trs)
+                else:
+                    stacked[sc] = jax.tree.map(
+                        lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]),
+                        *s_trs)
         return stacked[sc]
 
     out: List[List[RunResult | None]] = [[None] * len(cfgs) for _ in range(W)]
+    C = n_channels
+    # cutting a workload out: a static slice per leaf (one program), or an
+    # integer index (two) where single-channel inputs drop the stacking axis
+    slice_ops = 1 if multi else 2
     for (static, sc), idxs in _static_groups(cfgs).items():
-        batch = jax.tree.map(lambda *xs: jnp.stack(xs),
-                             *[cfgs[i].params(t) for i in idxs])
-        cnts = _dispatch_sweep(flat_for(sc), static, batch,
-                               chunk_len)  # (P, W*C, ...)
-        C = n_channels
+        flat = flat_for(sc)
+        P = len(idxs)
+        with span("repro.sweep.dispatch", mechanism=static.mechanism,
+                  configs=P, lanes=P * W * C):
+            cnts = _dispatch_sweep(flat, static,
+                                   _stack_params(cfgs, idxs, t),
+                                   chunk_len)  # (P, W*C, ...)
         for w in range(W):
-            # slice workload w back out; single-channel inputs also drop the
-            # stacking axis so results are shaped exactly like plain `sweep`
-            if multi:
-                cnt_w = jax.tree.map(
-                    lambda a, w=w: a[:, w * C:(w + 1) * C], cnts)
-            else:
-                cnt_w = jax.tree.map(lambda a, w=w: a[:, w], cnts)
-            results = _results_from_counters_batch(
-                cnt_w, [cfgs[i] for i in idxs], apps_list[w], C)
+            with span("repro.sweep.post", **_post_stats(P, slice_ops)):
+                # slice workload w back out; single-channel inputs also
+                # drop the stacking axis so results are shaped exactly
+                # like plain `sweep`
+                if multi:
+                    cnt_w = jax.tree.map(
+                        lambda a, w=w: a[:, w * C:(w + 1) * C], cnts)
+                else:
+                    cnt_w = jax.tree.map(lambda a, w=w: a[:, w], cnts)
+                results = _results_from_counters_batch(
+                    cnt_w, [cfgs[i] for i in idxs], apps_list[w], C)
             for j, i in enumerate(idxs):
                 out[w][i] = results[j]
     return out

@@ -1,6 +1,6 @@
 """``python -m repro.obs`` — the flight-recorder report (DESIGN.md §15/§16).
 
-Five sections, written into ``BENCH_obs.json`` (plus CSV/figure files):
+Four sections, written into ``BENCH_obs.json`` (plus CSV/figure files):
 
  1. **Telemetry tax** on the fig12 capacity grid: the identical chunked
     capacity sweep with telemetry off (``run_sweep_segment``) vs on with
@@ -21,8 +21,6 @@ Five sections, written into ``BENCH_obs.json`` (plus CSV/figure files):
     phase boundary, the dynamic the aggregate counters cannot show.
     Written as CSV always; as PNG too when matplotlib is importable
     (it is NOT a dependency of this repo).
- 5. **Entry-point profile**: compile-vs-execute wall estimates and warm
-    dispatch counts per registered compile contract (``obs.profile``).
 """
 from __future__ import annotations
 
@@ -40,12 +38,9 @@ from repro.core.timing import paper_config, shared_static
 from repro.analysis.contracts import CAPACITY_GRID, _stack_params
 from repro.obs import latency
 from repro.obs.telemetry import WindowCollector, series_csv, window_table
-from repro.obs.profile import profile_contracts
 
 # combined telemetry tax: window carry + §16 histogram planes + SLO counts
 TAX_TRIPWIRE = 1.25
-_QUICK_PROFILE = ("sweep.capacity", "streaming.chunked-replay",
-                  "obs.telemetry-sweep", "obs.tail-latency")
 
 
 def _grid_cfgs(period: int, slo_ns: int = 0):
@@ -211,7 +206,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="python -m repro.obs",
                                  description=__doc__.splitlines()[0])
     ap.add_argument("--quick", action="store_true",
-                    help="CI-sized traces and the short profile list")
+                    help="CI-sized traces")
     ap.add_argument("--json", default="BENCH_obs.json",
                     help="perf-record output path")
     ap.add_argument("--outdir", default=".",
@@ -222,8 +217,6 @@ def main(argv=None) -> int:
                     help="latency SLO threshold for the in-scan over-SLO "
                          "count (ns; <= 0 disables; 100 sits just under "
                          "the quick grid's p99, so violations are nonzero)")
-    ap.add_argument("--no-profile", action="store_true",
-                    help="skip the contract profiling section")
     args = ap.parse_args(argv)
 
     # 4096+ requests: below that, per-chunk dispatch constants (paid by
@@ -267,17 +260,6 @@ def main(argv=None) -> int:
           (f", figure -> {png_path}" if png_path
            else "  (no matplotlib: CSV only)"))
 
-    profile = {}
-    if not args.no_profile:
-        names = list(_QUICK_PROFILE) if args.quick else None
-        print(f"[obs] profiling "
-              f"{'quick subset' if args.quick else 'all contracts'}...")
-        profile = profile_contracts(names)
-        for name, rec in profile.items():
-            print(f"[obs]   {name}: cold {rec['cold_s']}s warm "
-                  f"{rec['warm_s']}s (compile est {rec['compile_s_est']}s, "
-                  f"jits {rec['jits_cold']}->{rec['jits_warm']})")
-
     record = {
         "bench": "obs", "quick": args.quick, **tax,
         "tail_latency": tail,
@@ -288,7 +270,6 @@ def main(argv=None) -> int:
             "max_hit_rate": round(float(pm["hit_rate"].max()), 4),
             "csv": csv_path, "png": png_path,
         },
-        "profile": profile,
     }
     with open(args.json, "w", encoding="utf-8") as f:
         json.dump(record, f, indent=2, sort_keys=True)

@@ -1,13 +1,22 @@
-"""Structured span/event log for the orchestration layer (DESIGN.md §15).
+"""Program spans on the profiler's clock, and the structured span/event
+log of the orchestration layer (DESIGN.md §15).
 
-A flat JSONL stream of Chrome-trace-shaped records: ``ph="B"``/``"E"``
-bracket a span, ``ph="i"`` is an instant event.  Timestamps come from an
-injected clock — the orchestrator passes its ``runtime.faults.
-LogicalClock`` — so a run under a seeded ``FaultPlan`` produces a
-byte-identical log every time (``tests/test_obs.py`` pins this); no wall
-clock ever enters a record.  Records are appended and flushed one write
-per event, so a SIGKILLed orchestrator still leaves every span it opened
-on disk (the CI ``kill-and-resume`` job uploads exactly that file).
+``span(name, **stats)`` is the one way program code marks a phase of its
+host path: a ``jax.profiler.TraceAnnotation`` that records a host event,
+with ``stats`` as the event's stats, on the same clock as the device
+trace when a profiler trace is active, and costs a TraceMe check
+otherwise.  Program span names start with ``repro.``; a span's counters
+are computed from shapes and Python values, never from device values.
+
+``Tracer`` writes a flat JSONL stream of Chrome-trace-shaped records:
+``ph="B"``/``"E"`` bracket a span, ``ph="i"`` is an instant event.
+Timestamps come from an injected clock — the orchestrator passes its
+``runtime.faults.LogicalClock`` — so a run under a seeded ``FaultPlan``
+produces a byte-identical log every time (``tests/test_obs.py`` pins
+this); no wall clock ever enters a record.  Records are appended and
+flushed one write per event, so a SIGKILLed orchestrator still leaves
+every span it opened on disk (the CI ``kill-and-resume`` job uploads
+exactly that file).
 
 ``chrome_trace`` / ``chrome_from_jsonl`` re-shape the log into the Chrome
 trace-event JSON format (a ``{"traceEvents": [...]}`` object) loadable in
@@ -21,8 +30,17 @@ import json
 import os
 from typing import Any, Callable, Dict, List, Optional
 
-__all__ = ["Tracer", "chrome_trace", "chrome_from_jsonl", "read_jsonl",
-           "counter_events", "telemetry_counter_events"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["span", "Tracer", "chrome_trace", "chrome_from_jsonl",
+           "read_jsonl", "counter_events", "telemetry_counter_events"]
+
+def span(name: str, **stats: Any) -> TraceAnnotation:
+    """A program span: ``with span("repro.sweep.post", configs=P): ...``.
+
+    On the profiler's clock when a trace is active, a no-op otherwise.
+    ``stats`` (ints, floats, strings) arrive as the host event's stats."""
+    return TraceAnnotation(name, **stats)
 
 
 def _encode(rec: Dict[str, Any]) -> str:
@@ -33,7 +51,10 @@ def _encode(rec: Dict[str, Any]) -> str:
 class Tracer:
     """Append-only span/event recorder.
 
-    ``clock`` is any zero-arg callable yielding monotonically
+    Every span (``span`` or a ``begin``/``end`` pair) is also a program
+    span ``repro.orch.<name>`` on the profiler's clock, its scalar attrs
+    as stats; the JSONL records are the same with or without a profiler
+    trace.  ``clock`` is any zero-arg callable yielding monotonically
     non-decreasing numbers; the orchestrator passes
     ``FaultPlan.clock.now`` so trace time is the same deterministic
     logical time its heartbeats and backoffs run on.  Without a clock a
@@ -50,6 +71,7 @@ class Tracer:
         if path and os.path.dirname(path):
             os.makedirs(os.path.dirname(path), exist_ok=True)
         self._f = open(path, "a", encoding="utf-8") if path else None
+        self._open: List[TraceAnnotation] = []   # program spans, LIFO
 
     def _emit(self, ph: str, name: str, attrs: Dict[str, Any]) -> None:
         rec = {"name": name, "ph": ph, "ts": self._clock(),
@@ -71,20 +93,38 @@ class Tracer:
 
     def begin(self, name: str, **attrs: Any) -> None:
         self._emit("B", name, attrs)
+        ann = span("repro.orch." + name,
+                   **{k: v for k, v in attrs.items()
+                      if isinstance(v, (int, float, str))})
+        ann.__enter__()
+        self._open.append(ann)
 
     def end(self, name: str, **attrs: Any) -> None:
         self._emit("E", name, attrs)
+        self._unwind(len(self._open) - 1)
+
+    def _unwind(self, depth: int) -> None:
+        """Close the program spans opened above ``depth``."""
+        while len(self._open) > max(depth, 0):
+            self._open.pop().__exit__(None, None, None)
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs: Any):
         """Bracket a scope with B/E records.  The E record is emitted on
         the success path only — a span left open in the log IS the signal
-        that the process died (or raised) inside it."""
+        that the process died (or raised) inside it.  The program spans
+        opened inside the scope close either way."""
+        depth = len(self._open)
         self.begin(name, **attrs)
-        yield self
+        try:
+            yield self
+        except BaseException:
+            self._unwind(depth)
+            raise
         self.end(name)
 
     def close(self) -> None:
+        self._unwind(0)
         if self._f is not None:
             self._f.close()
             self._f = None
