@@ -13,7 +13,8 @@ Phases on one chip, in order:
   campaign    the paper's 8-core campaign: 8 mixes x the six mechanisms,
               4 channels x 65536 requests per mix;
   crosscheck  one mix at 4096 requests per channel on the TPU and on the
-              host CPU device, every counter bitwise equal;
+              host CPU device, every counter bitwise equal, each group's
+              scan shown to run on its device;
   kernel      one static group with the fused Pallas FTS lookup against
               the same group without it, bitwise equal, with the kernel
               present in the compiled program;
@@ -54,10 +55,23 @@ def counters_equal(a, b) -> bool:
                for x, y in zip(a, b))
 
 
-def leaf_platforms(cnt) -> set:
+def scan_platforms(tr, cfgs) -> set:
+    """Platforms the scans of ``cfgs``' static groups run on under the
+    default device.  ``sweep``'s counters come back as host numpy, so this
+    dispatches each group's ``dram.run_sweep`` on the same inputs and reads
+    where its counters live before any post-processing."""
     import jax
-    return {d.platform for leaf in jax.tree.leaves(cnt)
-            for d in leaf.devices()}
+    import jax.numpy as jnp
+    from repro.core import dram, simulator
+    tr = jax.tree.map(jnp.asarray, tr)
+    out = set()
+    for (static, _), idxs in simulator.static_groups(cfgs).items():
+        batch = jax.tree.map(lambda *xs: jnp.stack(xs),
+                             *[cfgs[i].params() for i in idxs])
+        out |= {d.platform for leaf in jax.tree.leaves(
+                    dram.run_sweep(tr, static, batch))
+                for d in leaf.devices()}
+    return out
 
 
 def phase_campaign():
@@ -115,12 +129,13 @@ def phase_crosscheck():
     t_cpu = time.perf_counter() - t0
     equal = all(counters_equal(a.counters, b.counters)
                 for a, b in zip(on_tpu, on_cpu))
-    where = (set().union(*(leaf_platforms(r.counters) for r in on_tpu)),
-             set().union(*(leaf_platforms(r.counters) for r in on_cpu)))
-    check(equal and where == ({"tpu"}, {"cpu"}),
+    where = [scan_platforms(tr, cfgs)]
+    with jax.default_device(jax.devices("cpu")[0]):
+        where.append(scan_platforms(tr, cfgs))
+    check(equal and where == [{"tpu"}, {"cpu"}],
           f"[crosscheck] sweep {len(cfgs)} mechanisms, trace "
           f"(4, {CROSSCHECK_REQS}), tpu {t_tpu:.2f}s cpu {t_cpu:.2f}s "
-          f"(both incl. compile), counters on {where[0]} vs {where[1]}, "
+          f"(both incl. compile), scans on {where[0]} vs {where[1]}, "
           f"bitwise equal={equal}")
 
 

@@ -19,9 +19,13 @@ scan vmapped over the stacked dynamic params.  ``sweep_traces`` additionally
 stacks W traces along the (independent) channel axis — unequal lengths are
 no-op-padded (``dram.noop_pad``, DESIGN.md §9) — so a whole workloads x
 configs cross product runs per static structure as one program.
-Post-processing is vectorized over the params axis
+Post-processing copies each static group's stacked counters to the host
+once (one ``jax.device_get`` of every leaf) and makes every later cut —
+per workload, per config — as numpy indexing, so ``RunResult.counters``
+holds host ``np.ndarray`` leaves and no eager device program runs after
+the scan; the IPC/energy model is vectorized over the params axis
 (``_results_from_counters_batch``) so very large grids do not pay a
-Python-side loop for the IPC/energy model.  ``run_single_core`` /
+Python-side loop for it.  ``run_single_core`` /
 ``run_eight_core`` are thin wrappers that sweep one config per mechanism;
 ``run_single_core_batch`` / ``run_eight_core_batch`` are their stacked-trace
 counterparts (figs 7/8).
@@ -65,10 +69,6 @@ MLP_NON = 1.4
 PAPER_MECHS = ("base", "lisa_villa", "figcache_slow", "figcache_fast",
                "figcache_ideal", "lldram")
 
-# the counter leaves post-processing copies to the host, once each
-_HOST_LEAVES = tuple(f for f in dram.Counters._fields if f != "t_end")
-
-
 @dataclasses.dataclass
 class RunResult:
     mechanism: str
@@ -94,19 +94,19 @@ def _per_core_latency(cnt) -> Tuple[np.ndarray, np.ndarray]:
 def _results_from_counters_batch(cnts, cfgs: Sequence[MechConfig],
                                  apps: Sequence, n_channels: int
                                  ) -> List[RunResult]:
-    """Turn a stacked batch of ``dram.Counters`` into ``RunResult``s.
+    """Turn a stacked batch of host ``dram.Counters`` into ``RunResult``s.
 
-    Counter leaves carry a leading params axis ``(P, ...)`` (P == len(cfgs));
-    the MLP-weighted IPC model, execution time and the energy model all
-    evaluate vectorized over that axis, so post-processing a large grid is a
-    handful of numpy array ops instead of a Python loop (ROADMAP item).
-    Each leaf in ``_HOST_LEAVES`` is copied to the host once.
+    Counter leaves are host ``np.ndarray``s (callers ``jax.device_get``
+    them first) carrying a leading params axis ``(P, ...)`` (P ==
+    len(cfgs)); the MLP-weighted IPC model, execution time and the energy
+    model all evaluate vectorized over that axis, so post-processing a
+    large grid is a handful of numpy array ops instead of a Python loop.
+    Each result's ``counters`` is its config's numpy view ``a[i, ...]``,
+    shaped like the per-config scan's output.
     """
     P = len(cfgs)
-    host = cnts._replace(
-        **{k: np.asarray(getattr(cnts, k)) for k in _HOST_LEAVES})
-    lat = np.asarray(host.lat_sum_ns, dtype=np.float64)  # (P, [C,] cores)
-    req = np.asarray(host.req_cnt, dtype=np.float64)
+    lat = np.asarray(cnts.lat_sum_ns, dtype=np.float64)  # (P, [C,] cores)
+    req = np.asarray(cnts.req_cnt, dtype=np.float64)
     if lat.ndim == 3:                # multi-channel: sum over channels
         lat, req = lat.sum(1), req.sum(1)
     avg_lat = np.where(req > 0, lat / np.maximum(req, 1), 0.0)
@@ -123,10 +123,10 @@ def _results_from_counters_batch(cnts, cfgs: Sequence[MechConfig],
     exec_ns = np.where(r > 0, cycles / CPU_GHZ, 0.0).max(axis=1)
     instr_tot = instr.sum(axis=1)
     tot = lambda x: np.asarray(x, dtype=np.float64).reshape(P, -1).sum(axis=1)
-    n_req = tot(host.reads) + tot(host.writes)
-    parts = ENERGY.system_energy_nj_batch(host, n_channels, n_apps,
+    n_req = tot(cnts.reads) + tot(cnts.writes)
+    parts = ENERGY.system_energy_nj_batch(cnts, n_channels, n_apps,
                                           instr_tot, exec_ns, tot)
-    row_hits, cache_hits = tot(host.row_hits), tot(host.cache_hits)
+    row_hits, cache_hits = tot(cnts.row_hits), tot(cnts.cache_hits)
     out = []
     for i, cfg in enumerate(cfgs):
         div = n_req[i] if n_req[i] else 1.0
@@ -140,21 +140,19 @@ def _results_from_counters_batch(cnts, cfgs: Sequence[MechConfig],
             dram_energy_nj=float(parts["dram_total"][i]),
             system_energy_nj=float(parts["system_total"][i]),
             energy_parts={k: float(v[i]) for k, v in parts.items()},
-            counters=jax.tree.map(lambda a, i=i: a[i], cnts),
+            # `...` keeps a 0-d leaf an ndarray, not a numpy scalar
+            counters=jax.tree.map(lambda a, i=i: a[i, ...], cnts),
         ))
     return out
 
 
-def _post_stats(n_cfgs: int, slice_ops: int) -> dict:
+def _post_stats(n_cfgs: int, copies: bool) -> dict:
     """Stats of one ``repro.sweep.post`` span.  ``device_ops``: the device
-    programs its indexing launches — ``slice_ops`` per counter leaf to cut
-    one workload out, then per leaf and configuration an integer index,
-    which JAX runs as a dynamic slice and a squeeze; ``d2h_copies``: the
-    leaves ``_results_from_counters_batch`` copies to the host."""
-    n_leaves = len(dram.Counters._fields)
-    return {"configs": n_cfgs,
-            "device_ops": n_leaves * (slice_ops + 2 * n_cfgs),
-            "d2h_copies": len(_HOST_LEAVES)}
+    programs it launches, none since every cut is numpy indexing on host
+    arrays; ``d2h_copies``: the counter leaves it copies to the host, all
+    of them in a group's first post span (``copies``) and none after."""
+    return {"configs": n_cfgs, "device_ops": 0,
+            "d2h_copies": len(dram.Counters._fields) if copies else 0}
 
 
 def _stack_params(cfgs: Sequence[MechConfig], idxs: Sequence[int],
@@ -165,9 +163,10 @@ def _stack_params(cfgs: Sequence[MechConfig], idxs: Sequence[int],
 
 def _result_from_counters(cnt, cfg: MechConfig, apps: Sequence,
                           n_channels: int) -> RunResult:
-    """One config's ``Counters`` -> ``RunResult`` (P=1 batch, so the scalar
-    and swept paths share one arithmetic and agree to the last float)."""
-    one = jax.tree.map(lambda a: jnp.asarray(a)[None], cnt)
+    """One config's ``Counters`` -> ``RunResult`` (one host copy, then a
+    P=1 batch, so the scalar and swept paths share one arithmetic and
+    agree to the last float)."""
+    one = jax.tree.map(lambda a: np.asarray(a)[None], jax.device_get(cnt))
     return _results_from_counters_batch(one, [cfg], apps, n_channels)[0]
 
 
@@ -223,9 +222,10 @@ def sweep(trace: dram.Trace, cfgs: Sequence[MechConfig],
                   configs=P, lanes=P * n_channels):
             cnts = _dispatch_sweep(scheduled[sc], static,
                                    _stack_params(cfgs, idxs, t), chunk_len)
-        with span("repro.sweep.post", **_post_stats(P, 0)):
+        with span("repro.sweep.post", **_post_stats(P, True)):
             results = _results_from_counters_batch(
-                cnts, [cfgs[i] for i in idxs], apps, n_channels)
+                jax.device_get(cnts), [cfgs[i] for i in idxs], apps,
+                n_channels)
         for j, i in enumerate(idxs):
             out[i] = results[j]
     return out
@@ -336,9 +336,6 @@ def sweep_traces(trs: Sequence, cfgs: Sequence[MechConfig],
 
     out: List[List[RunResult | None]] = [[None] * len(cfgs) for _ in range(W)]
     C = n_channels
-    # cutting a workload out: a static slice per leaf (one program), or an
-    # integer index (two) where single-channel inputs drop the stacking axis
-    slice_ops = 1 if multi else 2
     for (static, sc), idxs in _static_groups(cfgs).items():
         flat = flat_for(sc)
         P = len(idxs)
@@ -348,7 +345,12 @@ def sweep_traces(trs: Sequence, cfgs: Sequence[MechConfig],
                                    _stack_params(cfgs, idxs, t),
                                    chunk_len)  # (P, W*C, ...)
         for w in range(W):
-            with span("repro.sweep.post", **_post_stats(P, slice_ops)):
+            with span("repro.sweep.post", **_post_stats(P, w == 0)):
+                if w == 0:
+                    # the group's one host copy: every leaf's transfer
+                    # starts before any is waited on (so this waits on
+                    # the scan); all later cuts are numpy views
+                    cnts = jax.device_get(cnts)
                 # slice workload w back out; single-channel inputs also
                 # drop the stacking axis so results are shaped exactly
                 # like plain `sweep`

@@ -127,10 +127,11 @@ def test_sweep_traces_spans_and_stats(traced_sweep):
     assert all(st["configs"] == 1 and st["lanes"] == W * C for st in disp)
     post = [st for n, _, _, st in spans if n == "repro.sweep.post"]
     assert len(post) == groups * W
-    # by hand: 12 leaves sliced once (one program each), then 12 leaves
-    # indexed for the one config (a dynamic slice and a squeeze each)
-    assert all(st == {"configs": 1, "device_ops": 12 + 12 * 2,
-                      "d2h_copies": 11} for st in post)
+    # by hand: a group's first post span copies its 12 leaves to the host
+    # at once; every cut after that is numpy indexing, no device program
+    assert post == [{"configs": 1, "device_ops": 0, "d2h_copies": 12},
+                    {"configs": 1, "device_ops": 0, "d2h_copies": 0}] \
+        * groups
     assert len(names) == len(sched) + len(stack) + len(disp) + len(post)
 
 
@@ -140,9 +141,22 @@ def test_sweep_spans_are_disjoint_siblings(traced_sweep):
         assert e0 <= s1, (n0, n1)
 
 
+def _first_of_group(spans):
+    """Post spans as (start, end, stats, first): ``first`` marks the one
+    that follows its group's dispatch span."""
+    out, prev = [], None
+    for n, s, e, st in spans:
+        if n == "repro.sweep.post":
+            out.append((s, e, st, prev == "repro.sweep.dispatch"))
+        prev = n
+    return out
+
+
 def test_post_device_ops_count_the_programs_run(tmp_path):
     """The ``device_ops`` stat equals the programs the CPU backend ran
-    inside each post span, on multi- and single-channel inputs."""
+    inside each post span, on multi- and single-channel inputs: none, as
+    each group's counters reach the host in one copy, made in its first
+    post span, and every cut after is numpy indexing."""
     spec = _specs()[0]
     multi = [workload.generate(spec)] * 2
     single = [jax.tree.map(lambda a: a[0], tr) for tr in multi]
@@ -153,12 +167,12 @@ def test_post_device_ops_count_the_programs_run(tmp_path):
         _, spans = _traced(d, lambda trs=trs: simulator.sweep_traces(
             trs, cfgs, [spec.apps()] * 2))
         runs = executions(str(d))
-        post = [(s, e, st) for n, s, e, st in spans
-                if n == "repro.sweep.post"]
+        post = _first_of_group(spans)
         assert len(post) == 2 * 2
-        for s, e, st in post:
-            assert st["device_ops"] == N_LEAVES * (
-                (1 if trs is multi else 2) + 2 * st["configs"])
+        assert [first for *_, first in post] == [True, False] * 2
+        for s, e, st, first in post:
+            assert st["device_ops"] == 0
+            assert st["d2h_copies"] == (N_LEAVES if first else 0)
             assert sum(s <= t < e for t in runs) == st["device_ops"]
 
 
@@ -179,8 +193,9 @@ def test_sweep_and_identity_schedule_spans(tmp_path):
     assert sorted(st["configs"] for st in disp) == [1, 2]
     assert all(st["lanes"] == st["configs"] * 2 for st in disp)
     post = [st for n, _, _, st in spans if n == "repro.sweep.post"]
-    assert sorted(st["device_ops"] for st in post) == [N_LEAVES * 2,
-                                                       N_LEAVES * 4]
+    assert sorted(st["configs"] for st in post) == [1, 2]
+    assert all(st["device_ops"] == 0 and st["d2h_copies"] == N_LEAVES
+               for st in post)
 
 
 def test_span_outside_a_trace_is_a_plain_context():

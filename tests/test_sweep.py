@@ -117,6 +117,42 @@ def test_simulator_sweep_matches_run_mechanism():
         assert ref.system_energy_nj == r.system_energy_nj
 
 
+@pytest.mark.parametrize("entry", ("sweep", "sweep_traces"))
+@pytest.mark.parametrize("multi", (True, False), ids=("multi", "single"))
+def test_results_are_host_numpy_and_match_run_mechanism(entry, multi):
+    """Post-processing hands back host ``np.ndarray`` counters, each leaf
+    shaped and typed like the per-config scan's output, and every result
+    bitwise equal to per-config ``run_mechanism``: counters, IPC, latency,
+    hit rates, execution time and energy."""
+    cfgs = [paper_config("base"),
+            paper_config("figcache_fast"),
+            paper_config("figcache_fast", insert_threshold=2)]
+    tr, apps = _trace(n_reqs=1024, multi=multi)
+    if entry == "sweep":
+        got = [(tr, simulator.sweep(tr, cfgs, apps))]
+    else:
+        tr2 = traces.build_trace(list(apps), 2 if multi else 1, 768, 9)
+        if not multi:
+            tr2 = jax.tree.map(lambda x: x[0], tr2)
+        res = simulator.sweep_traces([tr, tr2], cfgs, [apps, apps])
+        got = list(zip((tr, tr2), res))
+    for w, (t, row) in enumerate(got):
+        for cfg, r in zip(cfgs, row):
+            ref = simulator.run_mechanism(t, cfg, apps)
+            scan = (dram.run_channels if multi else dram.run_channel)(t, cfg)
+            for name, x, y, z in zip(scan._fields, scan, ref.counters,
+                                     r.counters):
+                ctx = (w, cfg.mechanism, name)
+                assert type(y) is np.ndarray and type(z) is np.ndarray, ctx
+                assert z.shape == x.shape and z.dtype == x.dtype, ctx
+                assert np.array_equal(x, z) and np.array_equal(y, z), ctx
+            assert np.array_equal(ref.ipc, r.ipc)
+            assert np.array_equal(ref.avg_lat_ns, r.avg_lat_ns)
+            for k in ("row_hit_rate", "cache_hit_rate", "exec_time_ns",
+                      "dram_energy_nj", "system_energy_nj", "energy_parts"):
+                assert getattr(ref, k) == getattr(r, k), (w, cfg, k)
+
+
 def _mini_trace(n, bank_of, row_of, col_of, core_of=lambda i: 0,
                 t_issue=lambda i: 0):
     idx = range(n)
