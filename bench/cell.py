@@ -1,21 +1,22 @@
 """A benchmark cell: its configuration and traffic files, and one point.
 
-Everything that belongs to one configuration or one traffic mix is a data
-file found by name (``configs/<config>.json``, ``traffic/<traffic>.json``);
-this module is the one general reader of both.  The unit of work is one
-campaign point: synthesize every mix of the traffic on the device from a
-seed, then simulate every configuration on every mix, until every result
-is finished: the derived numbers on the host, the counters on the device.
+Everything that belongs to one configuration, one traffic mix or one
+point path is a file found by name (``configs/<config>.json``,
+``traffic/<traffic>.json``, ``paths/<path>.py``); this module is the one
+general reader of all three.  The unit of work is one campaign point:
+synthesize every mix of the traffic from a seed, then simulate every
+configuration on every mix, until every result is finished.  The traffic
+file's ``path`` key (``sweep`` where it has none) names the program entry
+point a point runs through: the module's ``point(camp, seed, index)``.
 """
 from __future__ import annotations
 
 import dataclasses
 import hashlib
+import importlib.util
 import json
 import os
-import time
 
-import jax
 import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -40,6 +41,7 @@ class Cell:
     chips: int
     config: dict        # configs/<config>.json
     traffic: dict       # traffic/<traffic>.json
+    root: str = ROOT    # the checkout whose bench/ holds the cell's files
 
     @classmethod
     def load(cls, bench: dict, name: str, root: str = ROOT) -> "Cell":
@@ -48,7 +50,18 @@ class Cell:
         return cls(name=name, chips=w["chips"],
                    config=load_json(root, cfg["file"]),
                    traffic=load_json(root, "bench", "traffic",
-                                     w["traffic"] + ".json"))
+                                     w["traffic"] + ".json"), root=root)
+
+
+def load_path(name: str, root: str = ROOT):
+    """The point path module ``bench/paths/<name>.py`` under ``root``."""
+    path = os.path.join(root, "bench", "paths", name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no point path {name!r}: {path} is missing")
+    spec = importlib.util.spec_from_file_location(f"bench_path_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def point_seed(seed: int, index: int, mix: int) -> int:
@@ -59,8 +72,8 @@ def point_seed(seed: int, index: int, mix: int) -> int:
 
 
 class Campaign:
-    """The program's objects for one cell: configurations, controller and
-    the spec of each mix."""
+    """The program's objects for one cell: configurations, controller, the
+    spec of each mix, and the point path module (``self.path``)."""
 
     def __init__(self, cell: Cell):
         from repro.core import workload
@@ -73,6 +86,7 @@ class Campaign:
         self.cores = [tuple(workload.CoreWorkload(**c) for c in m["cores"])
                       for m in t["mixes"]]
         self._workload = workload
+        self.path = load_path(t.get("path", "sweep"), cell.root)
 
     def specs(self, seed: int, index: int):
         t = self.cell.traffic
@@ -93,19 +107,8 @@ class Point:
 
 
 def run_point(camp: Campaign, seed: int, index: int) -> Point:
-    """One campaign point through the program's sweep path."""
-    from repro.core import simulator, workload
-    t0 = time.perf_counter()
-    specs = camp.specs(seed, index)
-    with jax.profiler.TraceAnnotation("bench.point"):
-        with jax.profiler.TraceAnnotation("bench.synthesize"):
-            traces = jax.block_until_ready(workload.generate_many(specs))
-        t1 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("bench.simulate"):
-            res = simulator.sweep_traces(traces, camp.cfgs,
-                                         [s.apps() for s in specs])
-            jax.block_until_ready([r.counters for row in res for r in row])
-    return Point(index, traces, res, t0, t1, time.perf_counter())
+    """One campaign point through the cell's point path."""
+    return camp.path.point(camp, seed, index)
 
 
 def real_requests(trace) -> int:

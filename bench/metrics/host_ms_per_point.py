@@ -1,6 +1,7 @@
 """Host path: milliseconds per point inside the benchmark's ``simulate``
 span (``simulator.sweep_traces``: scheduling, stacking, dispatch and
-post-processing) during which no operation runs on the device.
+post-processing) during which no operation runs on the device, averaged
+over the chips.
 """
 from bench import tracing
 
@@ -9,11 +10,6 @@ SPANS = ("bench.simulate",)
 
 def read(ctx):
     red = ctx.red
-    if not red.devices or ctx.n_points <= 0:
-        return None
-    spans = tracing.union(tracing.clip(
-        [iv for name in SPANS for iv in red.spans_named(name)], red.window))
-    if not spans:
-        return None
-    idle = sum(tracing.subtract(spans, red.busy(d)) for d in red.devices)
-    return idle / len(red.devices) * 1e-6 / ctx.n_points
+    return tracing.idle_ms_per_point(
+        red, [iv for name in SPANS for iv in red.spans_named(name)],
+        ctx.n_points)

@@ -3,10 +3,11 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration file
-(``bench/configs/``) and a traffic file (``bench/traffic/``).  Set-up
-imports the program, loads the compile cache and runs one warm-up point;
-the window then runs whole campaign points back to back until the first
-one that ends after ``--seconds``.  ``--trace 1`` runs the same window
+(``bench/configs/``) and a traffic file (``bench/traffic/``), which names
+the point path (``bench/paths/``).  Set-up imports the program, loads the
+compile cache and runs one warm-up point; the window then runs whole
+campaign points back to back until the first one that ends after
+``--seconds``.  ``--trace 1`` runs the same window
 under the JAX profiler and reports the per-layer metrics
 (``bench/metrics/<name>.py``) instead of the end-to-end ones.  After the
 window one point drawn from the seed is recomputed in full (every mix,

@@ -3,7 +3,8 @@
 ``jax.profiler`` writes an ``.xplane.pb``; ``jax.profiler.ProfileData``
 reads it.  Device planes (``/device:TPU:<n>``) hold the programs the chip
 ran (line ``XLA Modules``); the host plane holds the benchmark's own spans
-(``bench.*``, written with ``jax.profiler.TraceAnnotation``).  The
+(``bench.*``, written with ``jax.profiler.TraceAnnotation``) and the
+program's (``repro.*``, ``obs.trace.span``), each with its stats.  The
 benchmark compiles without per-operation trace points (``run.py``), so a
 program execution is the finest device event: busy time is the union of
 program executions.  The reduction keeps intervals in nanoseconds and is
@@ -14,23 +15,28 @@ from __future__ import annotations
 import dataclasses
 import glob
 import os
-from typing import Dict, List, Tuple
+import warnings
+from typing import Dict, List, Optional, Tuple
 
 Interval = Tuple[int, int]          # [start, end) in ns
 
 DEVICE_PREFIX = "/device:TPU:"
 MODULE_LINE = "XLA Modules"
 SPAN_PREFIX = "bench."
+PROG_PREFIX = "repro."
 
 
 @dataclasses.dataclass
 class Reduced:
     """One traced window.  ``modules[d]`` lists device ``d``'s program
     executions as (name, start, end); ``spans`` the benchmark's host spans
-    as (name, start, end)."""
+    as (name, start, end); ``prog_spans`` the program's as (name, start,
+    end, stats)."""
     window: Interval
     modules: Dict[str, List[Tuple[str, int, int]]]
     spans: List[Tuple[str, int, int]]
+    prog_spans: List[Tuple[str, int, int, dict]] = dataclasses.field(
+        default_factory=list)
 
     @property
     def window_s(self) -> float:
@@ -65,6 +71,9 @@ class Reduced:
 
     def spans_named(self, name: str) -> List[Interval]:
         return [(s, e) for n, s, e in self.spans if n == name]
+
+    def prog_named(self, name: str) -> List[Interval]:
+        return [(s, e) for n, s, e, _ in self.prog_spans if n == name]
 
 
 def union(iv: List[Interval]) -> List[Interval]:
@@ -106,6 +115,20 @@ def subtract(iv: List[Interval], busy: List[Interval]) -> int:
     return tot
 
 
+def idle_ms_per_point(red: Reduced, spans: List[Interval],
+                      n_points: int) -> Optional[float]:
+    """Milliseconds per point inside the host ``spans`` in which no
+    operation runs on a device, averaged over the devices; ``None`` where
+    there is nothing to read."""
+    if not red.devices or n_points <= 0:
+        return None
+    spans = union(clip(spans, red.window))
+    if not spans:
+        return None
+    idle = sum(subtract(spans, red.busy(d)) for d in red.devices)
+    return idle / len(red.devices) * 1e-6 / n_points
+
+
 def load(log_dir: str):
     import jax
     files = sorted(glob.glob(os.path.join(log_dir, "plugins", "profile",
@@ -116,36 +139,46 @@ def load(log_dir: str):
 
 
 def reduce(pd, window_span: str = "bench.window") -> Reduced:
-    """Device programs and benchmark spans of one trace; the window is the
-    ``window_span`` host span."""
+    """Device programs, benchmark spans and program spans of one trace;
+    the window is the ``window_span`` host span."""
     modules: Dict[str, list] = {}
     spans: list = []
+    prog: list = []
     for plane in pd.planes:
         if plane.name.startswith(DEVICE_PREFIX):
-            modules[plane.name] = [ev for line in plane.lines
+            modules[plane.name] = [_interval(ev) for line in plane.lines
                                    if line.name == MODULE_LINE
-                                   for ev in _events(line)]
+                                   for ev in line.events]
         elif not plane.name.startswith("/device:"):
             for line in plane.lines:
-                spans.extend(ev for ev in _events(line)
-                             if ev[0].startswith(SPAN_PREFIX))
+                for ev in line.events:
+                    if ev.name.startswith(SPAN_PREFIX):
+                        spans.append(_interval(ev))
+                    elif ev.name.startswith(PROG_PREFIX):
+                        prog.append(_interval(ev) + (_stats(ev),))
     win = [(s, e) for n, s, e in spans if n == window_span]
     if not win:
         raise ValueError(f"the trace holds no {window_span!r} span")
     return Reduced(window=win[0], modules=modules,
-                   spans=[x for x in spans if x[0] != window_span])
+                   spans=[x for x in spans if x[0] != window_span],
+                   prog_spans=prog)
 
 
-def _events(line):
-    return [(ev.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns))
-            for ev in line.events]
+def _interval(ev):
+    return (ev.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns))
+
+
+def _stats(ev) -> dict:
+    with warnings.catch_warnings():     # the stats' type has no __module__
+        warnings.simplefilter("ignore", DeprecationWarning)
+        return dict(ev.stats)
 
 
 def breakdown(red: Reduced, top: int = 10) -> dict:
     """The device programs that took most time (seconds summed over the
     window, averaged over the devices) and the longest idle gaps of the
-    first device, each named by the innermost benchmark span open at its
-    start."""
+    first device, each named by the span that holds most of it
+    (``holder``)."""
     per: Dict[str, int] = {}
     for d in red.devices:
         for n, s, e in red.modules[d]:
@@ -157,16 +190,24 @@ def breakdown(red: Reduced, top: int = 10) -> dict:
                         key=lambda x: -x[1])[:top]
     idle = []
     if red.devices:
-        for s, e in gaps(red.busy(red.devices[0]), red.window):
-            idle.append([innermost(red.spans, s), (e - s) * 1e-9])
-    idle.sort(key=lambda x: -x[1])
-    return {"device_ops": device_ops, "idle_gaps": idle[:top]}
+        longest = sorted(gaps(red.busy(red.devices[0]), red.window),
+                         key=lambda g: g[0] - g[1])[:top]
+        spans = red.spans + [x[:3] for x in red.prog_spans]
+        idle = [[holder(spans, g), (g[1] - g[0]) * 1e-9] for g in longest]
+    return {"device_ops": device_ops, "idle_gaps": idle}
 
 
-def innermost(spans, t: int) -> str:
-    """The shortest benchmark span open at ``t`` (``window`` if none)."""
-    best = None
-    for n, s, e in spans:
-        if s <= t < e and (best is None or e - s < best[1]):
-            best = (n, e - s)
-    return best[0] if best else "window"
+def holder(spans, gap: Interval) -> str:
+    """The span that holds most of ``gap``: each instant of the gap goes
+    to the shortest span open at it, its innermost, or to ``window``
+    where none is open; the name given most time wins."""
+    s0, e0 = gap
+    inside = [(n, max(s, s0), min(e, e0), e - s) for n, s, e in spans
+              if s < e0 and e > s0]
+    cuts = sorted({s0, e0, *(t for _, s, e, _ in inside for t in (s, e))})
+    held: Dict[str, int] = {}
+    for a, b in zip(cuts, cuts[1:]):
+        open_ = [(ln, n) for n, s, e, ln in inside if s <= a and b <= e]
+        name = min(open_)[1] if open_ else "window"
+        held[name] = held.get(name, 0) + b - a
+    return max(sorted(held), key=held.get)
