@@ -4,9 +4,9 @@ Two halves on top of the bank/bus/MSHR model of ``core/dram.py``:
 
  * ``policies`` — per-bank request queues with pluggable disciplines
    (FCFS, FR-FCFS row-hit-first with a starvation cap, write-drain
-   batching), realized as host-side trace-preprocessing permutations
-   keyed by ``timing.SchedConfig`` so a whole controller grid replays
-   through one compiled scan.
+   batching), realized as trace-preprocessing permutations computed on
+   the device and keyed by ``timing.SchedConfig``, so a whole controller
+   grid replays through one compiled scan.
  * ``wavefront`` — bank-parallel execution: a compile pass groups the
    (scheduled) trace into distinct-bank waves and one ``lax.scan`` step
    retires a whole wave, vmapping the serial scan's own per-request

@@ -4,7 +4,7 @@ The simulator's seed contract was "the trace order IS the schedule"
 (DESIGN.md §7).  This module adds the controller the paper actually
 evaluates under (§7, FR-FCFS): a ``timing.SchedConfig`` names a scheduling
 discipline and ``schedule`` realizes it as a **per-channel service-order
-permutation** computed on the host *before* the compiled scan runs.
+permutation** computed on the device *before* the compiled scan runs.
 Arrival times (``t_issue``) are untouched — only the order in which the
 controller serves requests changes — so a scheduled trace has exactly the
 shape and dtype of its input and replays through the very same compiled
@@ -34,18 +34,28 @@ Model, per channel:
    only latency statistics are affected — documented in DESIGN.md §10.)
 
 No-op padding requests (``dram.NOOP_ISSUE``) are never reordered: the real
-prefix is scheduled and the no-ops are re-appended, preserving the
-"padding is a suffix" invariant of ``simulator.sweep_traces``.
+requests are scheduled and the no-ops follow them in index order,
+preserving the "padding is a suffix" invariant of
+``simulator.sweep_traces``.
 
-Everything here is numpy/Python — traces are built once and cached by the
-benchmark layer, and the pass is O(T * queue_depth).
+``schedule`` computes every lane of a (..., T) batch in one jitted
+program (``service_order``): the write drain is one stable sort per lane
+and the FR-FCFS walk one ``lax.scan`` of T trips, vmapped over lanes, so
+a point's whole stacked batch is one dispatch and nothing is copied to the
+host.  ``write_drain_perm`` and ``frfcfs_perm`` are the same two passes in
+plain Python, kept as the oracle the tests hold the device form to, and
+``StreamScheduler`` is their incremental form for chunked replays.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import List, Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core.dram import NOOP_ISSUE, Trace
 from repro.obs.trace import span
@@ -124,7 +134,8 @@ def frfcfs_perm(bank: Sequence[int], row: Sequence[int],
 def _schedule_channel(t: np.ndarray, bank: np.ndarray, row: np.ndarray,
                       is_write: np.ndarray, sc: SchedConfig,
                       n_banks: int) -> np.ndarray:
-    """Service-order permutation for one channel's arrays."""
+    """Service-order permutation for one channel's arrays, in plain
+    Python: the oracle of ``service_order``."""
     real = np.flatnonzero(t < NOOP_ISSUE)
     bl, rl, wl = bank.tolist(), row.tolist(), is_write.tolist()
     order: List[int] = real.tolist()
@@ -139,36 +150,127 @@ def _schedule_channel(t: np.ndarray, bank: np.ndarray, row: np.ndarray,
         if noops.size else np.asarray(order, np.int64)
 
 
+def _drain_slots(bank, row, wr, rd, D: int):
+    """Every real request's slot in ``write_drain_perm``'s order, for one
+    lane (``wr``/``rd``: real writes/reads).  A read takes the first free
+    slot: one after every earlier read and every batch of D writes closed
+    before it.  A full batch starts at the free slot where its last write
+    arrives, the final partial batch after everything else, and within a
+    batch writes go by (bank, row), then arrival."""
+    T = wr.shape[0]
+    n = functools.partial(jnp.sum, dtype=jnp.int32)
+    rank = jnp.cumsum(wr, dtype=jnp.int32) - 1       # among the writes
+    closes = wr & (rank % D == D - 1)
+    free = jnp.cumsum(rd, dtype=jnp.int32) - rd \
+        + D * (jnp.cumsum(closes, dtype=jnp.int32) - closes)
+    start = lax.cummin(jnp.where(closes, free, n(rd) + D * n(closes)),
+                       reverse=True)
+    # each batch's D write ranks sorted by (bank, row, rank); empty slots
+    # of the partial batch sort last
+    nb = -(-T // D)
+    at = jnp.where(wr, rank, nb * D)
+
+    def batched(x):
+        return jnp.full(nb * D, 2 ** 31 - 1, jnp.int32).at[at].set(
+            x, mode="drop").reshape(nb, D)
+
+    by = lax.sort((batched(bank), batched(row),
+                   jnp.arange(nb * D, dtype=jnp.int32).reshape(nb, D)),
+                  num_keys=3)[-1]
+    place = jnp.zeros(nb * D, jnp.int32).at[by.ravel()].set(
+        jnp.tile(jnp.arange(D, dtype=jnp.int32), nb), unique_indices=True)
+    return jnp.where(wr, start + place[rank], free)
+
+
+def _drain_order(t, bank, row, is_write, sc: SchedConfig):
+    """One lane's order after the write-drain stage, no-ops last in index
+    order: each request's slot, then one scatter (no sort of the lane)."""
+    T = t.shape[0]
+    real = t < NOOP_ISSUE
+    slot = jnp.where(real, jnp.cumsum(real, dtype=jnp.int32) - 1,
+                     jnp.sum(real, dtype=jnp.int32)
+                     + jnp.cumsum(~real, dtype=jnp.int32) - 1)
+    if sc.write_drain:
+        slot = jnp.where(real, _drain_slots(bank, row, real & is_write,
+                                            real & ~is_write,
+                                            sc.drain_batch), slot)
+    return jnp.zeros(T, jnp.int32).at[slot].set(
+        jnp.arange(T, dtype=jnp.int32), unique_indices=True)
+
+
+def _frfcfs_walk(order, t, bank, row, n_real, sc: SchedConfig,
+                 n_banks: int):
+    """``frfcfs_perm`` over one lane's ``order[:n_real]`` as a scan of T
+    trips (the trips past ``n_real`` emit garbage the caller drops).  The
+    carry holds the window's positions and their (t, bank, row), so a
+    trip reads nothing but its refill, ``order[s + queue_depth]``."""
+    T, Q = order.shape[0], sc.queue_depth
+    # the differences compared are of real arrival times (< 2**30)
+    aw = min(sc.arrival_window_ns * TICKS_PER_NS, 2 ** 31 - 1)
+    cols = jnp.pad(jnp.stack([order, t[order], bank[order], row[order]]),
+                   ((0, 0), (0, Q)))
+    slot = jnp.arange(Q, dtype=jnp.int32)
+    banks = jnp.arange(n_banks, dtype=jnp.int32)
+
+    def trip(carry, refill):
+        win, last_row, m, bypass, s = carry        # win: (4, Q)
+        _, t_w, bank_w, row_w = win
+        open_row = jnp.sum(jnp.where(bank_w[:, None] == banks, last_row, 0),
+                           axis=1)
+        hit = (slot < m) & (t_w - t_w[0] <= aw) & (row_w == open_row)
+        first = jnp.min(jnp.where(hit, slot, Q))
+        pick = jnp.where((bypass < sc.starve_cap) & (first < Q), first, 0)
+        i, _, b, r = jnp.sum(jnp.where(slot == pick, win, 0), axis=1)
+        last_row = jnp.where(banks == b, r, last_row)
+        bypass = jnp.where(pick == 0, 0, bypass + 1)
+        shifted = jnp.concatenate([win[:, 1:], refill[:, None]], axis=1)
+        win = jnp.where(slot < pick, win, shifted)
+        m = jnp.where(s + Q < n_real, m, m - 1)
+        return (win, last_row, m, bypass, s + 1), i
+
+    zero = jnp.int32(0)
+    init = (cols[:, :Q], jnp.full(n_banks, -1, jnp.int32),
+            jnp.minimum(Q, n_real), zero, zero)
+    return lax.scan(trip, init, cols[:, Q:].T)[1]
+
+
+def _lane_order(t, bank, row, is_write, sc: SchedConfig, n_banks: int):
+    order = _drain_order(t, bank, row, is_write, sc)
+    if sc.policy != "frfcfs":
+        return order
+    n_real = jnp.sum(t < NOOP_ISSUE, dtype=jnp.int32)
+    walked = _frfcfs_walk(order, t, bank, row, n_real, sc, n_banks)
+    return jnp.where(jnp.arange(t.shape[0]) < n_real, walked, order)
+
+
+@functools.partial(jax.jit, static_argnames=("sc", "n_banks"))
+def service_order(trace: Trace, sc: SchedConfig, n_banks: int) -> Trace:
+    """Every lane of a (..., T) trace in ``sc``'s service order: one
+    program for the whole batch, on the device."""
+    shape = trace.t_issue.shape
+    lanes = jax.tree.map(lambda x: jnp.reshape(x, (-1, shape[-1])), trace)
+    perm = jax.vmap(functools.partial(_lane_order, sc=sc, n_banks=n_banks))(
+        lanes.t_issue, lanes.bank, lanes.row, lanes.is_write)
+    return jax.tree.map(
+        lambda x: jnp.take_along_axis(x, perm, axis=1).reshape(shape), lanes)
+
+
 def schedule(trace: Trace, sc: Optional[SchedConfig],
              geom: DRAMGeometry = GEOM) -> Trace:
-    """Reorder a (T,) or (C, T) trace into the service order ``sc``'s
-    controller would issue.  FCFS (or ``sc=None``) returns the trace
-    object untouched — the existing zero-controller behavior.  Each call
-    is one ``repro.sched.schedule`` span (stats: ``policy``, ``requests``,
-    the trace's entries)."""
+    """Reorder a trace into the service order ``sc``'s controller would
+    issue.  Every leading index is a lane (one channel): (T,), (C, T), or
+    a stacked (W*C, T) batch.  FCFS (or ``sc=None``) returns the trace
+    object untouched — the existing zero-controller behavior; any other
+    controller is one ``service_order`` program over every lane.  Each
+    call is one ``repro.sched.schedule`` span (stats: ``policy``;
+    ``requests``, the trace's entries; ``lanes``)."""
+    shape = np.shape(trace.t_issue)
     with span("repro.sched.schedule",
               policy=SCHED_FCFS.policy if sc is None else sc.policy,
-              requests=math.prod(np.shape(trace.t_issue))):
-        return _schedule(trace, sc, geom)
-
-
-def _schedule(trace: Trace, sc: Optional[SchedConfig],
-              geom: DRAMGeometry) -> Trace:
-    if sc is None or sc.is_identity:
-        return trace
-    t = np.asarray(trace.t_issue)
-    leaves = {name: np.asarray(x) for name, x in trace._asdict().items()}
-    if t.ndim == 1:
-        perm = _schedule_channel(t, leaves["bank"], leaves["row"],
-                                 leaves["is_write"], sc, geom.n_banks)
-        return Trace(**{k: v[perm] for k, v in leaves.items()})
-    chans = []
-    for c in range(t.shape[0]):
-        perm = _schedule_channel(t[c], leaves["bank"][c], leaves["row"][c],
-                                 leaves["is_write"][c], sc, geom.n_banks)
-        chans.append({k: v[c][perm] for k, v in leaves.items()})
-    return Trace(**{k: np.stack([ch[k] for ch in chans])
-                    for k in leaves})
+              requests=math.prod(shape), lanes=math.prod(shape[:-1])):
+        if sc is None or sc.is_identity:
+            return trace
+        return service_order(trace, sc, geom.n_banks)
 
 
 class StreamScheduler:

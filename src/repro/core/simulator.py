@@ -32,11 +32,11 @@ counterparts (figs 7/8).
 
 Host-path spans (DESIGN.md §15): ``sweep`` and ``sweep_traces`` mark each
 phase of their host path with an ``obs.trace.span`` on the profiler's
-clock — ``repro.sched.schedule`` (in ``sched_policies.schedule``),
-``repro.sweep.stack`` (no-op padding and channel stacking, once per
-controller), ``repro.sweep.dispatch`` (once per static group) and
-``repro.sweep.post`` (once per group and workload) — siblings, disjoint
-in time, whose stats are counted from shapes and Python values.
+clock — ``repro.sched.schedule`` (in ``sched_policies.schedule``, once
+per controller), ``repro.sweep.stack`` (no-op padding and channel
+stacking, once per call), ``repro.sweep.dispatch`` (once per static
+group) and ``repro.sweep.post`` (once per group and workload) — siblings,
+disjoint in time, whose stats are counted from shapes and Python values.
 
 Workloads are first-class sweep axes too (DESIGN.md §11): ``sweep_traces``
 accepts ``workload.WorkloadSpec`` entries and synthesizes those traces on
@@ -213,7 +213,7 @@ def sweep(trace: dram.Trace, cfgs: Sequence[MechConfig],
     multi = np.asarray(trace.t_issue).ndim == 2
     n_channels = np.asarray(trace.t_issue).shape[0] if multi else 1
     out: List[RunResult | None] = [None] * len(cfgs)
-    scheduled: Dict[object, dram.Trace] = {}   # host pass once per controller
+    scheduled: Dict[object, dram.Trace] = {}   # one call per controller
     for (static, sc), idxs in _static_groups(cfgs).items():
         if sc not in scheduled:
             scheduled[sc] = sched_policies.schedule(trace, sc)
@@ -313,35 +313,24 @@ def sweep_traces(trs: Sequence, cfgs: Sequence[MechConfig],
     n_channels = np.asarray(trs[0].t_issue).shape[0] if multi else 1
     W = len(trs)
     t_max = max(np.asarray(tr.t_issue).shape[-1] for tr in trs)
-    stacked: Dict[object, dram.Trace] = {}
-
-    def flat_for(sc):
-        """Channel-stack the W workload traces under controller ``sc``
-        (all scheduled first, then no-op padded, so the no-op suffix
-        invariant holds and the stack span holds no schedule span);
-        memoized per distinct controller."""
-        if sc not in stacked:
-            s_trs = [sched_policies.schedule(tr, sc) for tr in trs]
-            with span("repro.sweep.stack", workloads=W, trips=t_max):
-                s_trs = [dram.noop_pad(tr, t_max) for tr in s_trs]
-                if multi:
-                    stacked[sc] = jax.tree.map(
-                        lambda *xs: jnp.concatenate(
-                            [jnp.asarray(x) for x in xs], axis=0), *s_trs)
-                else:
-                    stacked[sc] = jax.tree.map(
-                        lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]),
-                        *s_trs)
-        return stacked[sc]
+    # no-op pad, then stack: no-ops stay a suffix of every lane, so one
+    # schedule call per controller on the stack equals per-workload calls
+    with span("repro.sweep.stack", workloads=W, trips=t_max):
+        padded = [dram.noop_pad(tr, t_max) for tr in trs]
+        join = jnp.concatenate if multi else jnp.stack
+        flat = jax.tree.map(
+            lambda *xs: join([jnp.asarray(x) for x in xs]), *padded)
+    scheduled: Dict[object, dram.Trace] = {}   # one call per controller
 
     out: List[List[RunResult | None]] = [[None] * len(cfgs) for _ in range(W)]
     C = n_channels
     for (static, sc), idxs in _static_groups(cfgs).items():
-        flat = flat_for(sc)
+        if sc not in scheduled:
+            scheduled[sc] = sched_policies.schedule(flat, sc)
         P = len(idxs)
         with span("repro.sweep.dispatch", mechanism=static.mechanism,
                   configs=P, lanes=P * W * C):
-            cnts = _dispatch_sweep(flat, static,
+            cnts = _dispatch_sweep(scheduled[sc], static,
                                    _stack_params(cfgs, idxs, t),
                                    chunk_len)  # (P, W*C, ...)
         for w in range(W):

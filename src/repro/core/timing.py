@@ -117,7 +117,7 @@ class SchedConfig:
     harness had none ("the trace order is the schedule").  A ``SchedConfig``
     names a controller: ``core/sched/policies.py`` realizes it as a
     *trace-preprocessing* pass (a per-channel service-order permutation) that
-    runs on the host before the compiled scan, so the scheduling knobs never
+    runs on the device before the compiled scan, so the scheduling knobs never
     enter the scan and a policy grid reuses ONE compilation — the scheduled
     traces all share the original trace's shape.  It lives here next to
     ``StaticConfig`` / ``MechParams`` because it is the third leg of a

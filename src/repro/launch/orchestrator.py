@@ -46,7 +46,7 @@ completion under faults:
 
 Resume-equivalence argument (the §14 guarantee): shard counters are a pure
 function of (scheduled trace, params) — the scheduler permutation is
-host-deterministic, chunking is bitwise-invariant (PR 7), checkpoint/restore
+deterministic, chunking is bitwise-invariant (§13), checkpoint/restore
 round-trips the exact carry bytes, and re-execution after a kill either
 reuses a committed result (first-commit-wins) or recomputes the same pure
 function.  Hence ANY interleaving of kills and resumes yields counters
@@ -292,7 +292,8 @@ class Orchestrator:
     def _shard_inputs(self, shard: Shard):
         """Regenerate the shard's (scheduled trace, static, params batch).
         Deterministic: the spec synthesizes the same trace on every
-        process, and scheduling is a host-side pure permutation."""
+        process, and scheduling is a pure permutation computed on the
+        device (``sched_policies.service_order``)."""
         spec = self.plan.specs[shard.w]
         cfgs = [self.plan.cfgs[i] for i in shard.cfg_idxs]
         static = shared_static(cfgs)

@@ -16,7 +16,9 @@ Contracts:
     respects the starvation cap (replay-checked), degenerates to FCFS at
     ``starve_cap=0``, preserves per-(bank, row) FIFO order, and actually
     reorders a crafted row-conflict trace; write-drain defers writes in
-    (bank, row)-sorted batches; sched-carrying configs route through
+    (bank, row)-sorted batches; the device service order equals the
+    plain-Python oracle (``write_drain_perm`` + ``frfcfs_perm``) lane by
+    lane, and one call on a stacked batch equals per-workload calls; sched-carrying configs route through
     ``simulator.sweep`` bitwise-identically to per-config runs.
 """
 import functools
@@ -27,8 +29,8 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import dram, sched, simulator, traces
-from repro.core.sched import wavefront
+from repro.core import dram, sched, simulator, traces, workload
+from repro.core.sched import policies, wavefront
 from repro.core.timing import GEOM, SchedConfig, paper_config
 
 POLICIES = ("row_benefit", "segment_benefit", "lru", "random")
@@ -253,6 +255,109 @@ def test_frfcfs_is_permutation_and_respects_starve_cap(seed, qd, cap, drain):
             bypass += 1
             assert bypass <= cap, (p, bypass, cap)
         pending.remove(p)
+
+
+def _lanes(seed=5, T=96):
+    """Six lanes: plain, ragged no-op suffix, interior no-ops, all no-op,
+    and two with few rows (many hits); arrival gaps include 0 ticks."""
+    rng = np.random.default_rng(seed)
+    L = 6
+    t = np.cumsum(rng.integers(0, 40, (L, T)), axis=1).astype(np.int32)
+    t[1, T // 3:] = dram.NOOP_ISSUE
+    t[2, ::5] = dram.NOOP_ISSUE
+    t[3] = dram.NOOP_ISSUE
+    rows = np.where(np.arange(L)[:, None] >= 4, 2, 8)
+    return dram.Trace(
+        t_issue=t,
+        bank=rng.integers(0, GEOM.n_banks, (L, T)).astype(np.int32),
+        row=(rng.integers(0, 8, (L, T)) % rows).astype(np.int32),
+        col=rng.integers(0, 128, (L, T)).astype(np.int32),
+        is_write=rng.random((L, T)) < 0.4,
+        core=rng.integers(0, GEOM.n_cores, (L, T)).astype(np.int32))
+
+
+def _assert_oracle_order(tr, sc):
+    """``schedule`` equals ``_schedule_channel``'s plain-Python order on
+    every lane of a (L, T) trace, in every field."""
+    got = sched.schedule(tr, sc)
+    for lane in range(np.shape(tr.t_issue)[0]):
+        ch = [np.asarray(x)[lane] for x in tr]
+        perm = policies._schedule_channel(ch[0], ch[1], ch[2], ch[4], sc,
+                                          GEOM.n_banks)
+        for f, x, y in zip(dram.Trace._fields, ch, got):
+            assert np.array_equal(np.asarray(y)[lane], x[perm]), \
+                (sc, lane, f)
+
+
+def _oracle_grid():
+    for qd in (1, 4, 32):
+        for cap in (0, 1, 16):
+            for aw in (0, 50, 10 ** 6):
+                for drain in (None, 1, 16):
+                    yield SchedConfig("frfcfs", queue_depth=qd,
+                                      starve_cap=cap, arrival_window_ns=aw,
+                                      write_drain=drain is not None,
+                                      drain_batch=drain or 16)
+    for drain in (1, 16):
+        yield SchedConfig("fcfs", write_drain=True, drain_batch=drain)
+
+
+@pytest.mark.parametrize("sc", list(_oracle_grid()),
+                         ids=lambda sc: f"{sc.policy}-q{sc.queue_depth}"
+                         f"-c{sc.starve_cap}-w{sc.arrival_window_ns}"
+                         f"-d{int(sc.write_drain) and sc.drain_batch}")
+def test_device_order_matches_oracle(sc):
+    _assert_oracle_order(_lanes(), sc)
+
+
+def _edge_trace(case):
+    tr = _lanes(seed=9, T=20 if case == "short" else 96)
+    if case == "all_writes":
+        tr = tr._replace(is_write=np.ones_like(tr.is_write))
+    elif case == "all_reads":
+        tr = tr._replace(is_write=np.zeros_like(tr.is_write))
+    return tr
+
+
+@pytest.mark.parametrize("case", ["short", "all_writes", "all_reads"])
+@pytest.mark.parametrize("drain", [False, True])
+def test_device_order_matches_oracle_edge_traces(case, drain):
+    """T < queue_depth (20 < 32), every request a write, none a write."""
+    _assert_oracle_order(_edge_trace(case),
+                         SchedConfig("frfcfs", queue_depth=32,
+                                     write_drain=drain))
+
+
+def test_device_order_matches_oracle_bench_shape():
+    """One paper8.frfcfs mix as the benchmark generates it: 4 channels x
+    8192 requests under queue 32, cap 16, window 50 ns, drain 16."""
+    _, _, apps = traces.eight_core_workloads()[0]
+    (tr,) = workload.generate_many([workload.spec_from_apps(apps, 4, 8192,
+                                                            seed=1)])
+    _assert_oracle_order(tr, SchedConfig("frfcfs", queue_depth=32,
+                                          starve_cap=16,
+                                          arrival_window_ns=50,
+                                          write_drain=True, drain_batch=16))
+
+
+@pytest.mark.parametrize("multi", [False, True])
+def test_schedule_stacked_equals_per_workload(multi):
+    """One call on a padded stack == the stack of per-workload calls,
+    padded: no-ops stay a suffix of every lane."""
+    full = _lanes(seed=13)
+    C = 2 if multi else 1
+    parts = [jax.tree.map(lambda x: np.asarray(x)[w * C:(w + 1) * C, :n],
+                          full) for w, n in enumerate((96, 70, 41))]
+    if not multi:
+        parts = [jax.tree.map(lambda x: x[0], p) for p in parts]
+    join = np.concatenate if multi else np.stack
+    sc = SchedConfig("frfcfs", queue_depth=8, write_drain=True, drain_batch=4)
+    stacked = sched.schedule(jax.tree.map(lambda *xs: join(xs), *[
+        dram.noop_pad(p, 96) for p in parts]), sc)
+    per = jax.tree.map(lambda *xs: join(xs), *[
+        dram.noop_pad(sched.schedule(p, sc), 96) for p in parts])
+    for f, x, y in zip(dram.Trace._fields, per, stacked):
+        assert np.array_equal(np.asarray(x), np.asarray(y)), f
 
 
 def test_frfcfs_starve_cap_zero_is_fcfs():

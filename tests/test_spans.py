@@ -114,14 +114,13 @@ def test_sweep_traces_spans_and_stats(traced_sweep):
     names = [n for n, *_ in spans]
     W, C, groups = 2, 2, 4
     t_max = max(tr.t_issue.shape[-1] for tr in trs)
-    # one schedule per workload per controller, one stack per controller
+    # one stack; one schedule per controller, over the whole stacked batch
     sched = [st for n, _, _, st in spans if n == "repro.sched.schedule"]
-    assert sorted(st["policy"] for st in sched) == ["fcfs"] * W \
-        + ["frfcfs"] * W
-    assert sorted(st["requests"] for st in sched) == sorted(
-        [int(np.prod(tr.t_issue.shape)) for tr in trs] * 2)
+    assert sorted(st["policy"] for st in sched) == ["fcfs", "frfcfs"]
+    assert all(st["lanes"] == W * C and st["requests"] == W * C * t_max
+               for st in sched)
     stack = [st for n, _, _, st in spans if n == "repro.sweep.stack"]
-    assert stack == [{"workloads": W, "trips": t_max}] * 2
+    assert stack == [{"workloads": W, "trips": t_max}]
     disp = [st for n, _, _, st in spans if n == "repro.sweep.dispatch"]
     assert sorted(st["mechanism"] for st in disp) == sorted(MECHS * 2)
     assert all(st["configs"] == 1 and st["lanes"] == W * C for st in disp)
