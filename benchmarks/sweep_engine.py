@@ -25,10 +25,11 @@ n_slots), so the 1-compilation behavior is not bought with a semantics
 change.
 
 The HOT-LOOP section (DESIGN.md §9) measures per-step cost on the default
-fig-12 capacity grid: the ``"dense"`` scan variant re-derives every FTS
-decision from scratch each step (the pre-aggregate loop), the default
-``"fused"`` variant updates carried aggregates with per-(bank, slot)
-scalar writes.  Both are bitwise-identical (``tests/test_hotloop.py``);
+fig-12 capacity grid: the dense reference body (``dram.simulate_dense``)
+re-derives every FTS decision from scratch each step (the pre-aggregate
+loop), the fused scan body (``dram.run_sweep``) updates carried
+aggregates with per-(bank, slot) scalar writes.  Both are
+bitwise-identical (``tests/test_hotloop.py``);
 steps/sec and the speedup land in ``BENCH_hotloop.json`` so the perf
 trajectory is recorded per PR (CI uploads it to the job summary).
 
@@ -68,6 +69,7 @@ entry per trace).
 """
 from __future__ import annotations
 
+import functools
 import json
 import time
 
@@ -125,6 +127,14 @@ def _shape_grid_jits(tr, grid_kw, label):
     return jits
 
 
+@functools.partial(jax.jit, static_argnums=(1,))
+def _sweep_dense(trace, static, params_batch):
+    """The dense reference body vmapped over a params batch: the hot-loop
+    baseline ``run_sweep`` is measured against."""
+    return jax.vmap(lambda p: dram.simulate_dense(trace, static, p))(
+        params_batch)
+
+
 def _hotloop_report(tr):
     """steps/sec of the fused vs dense scan bodies on the fig-12 capacity
     grid (one compiled scan each), plus their bitwise cross-check."""
@@ -134,16 +144,15 @@ def _hotloop_report(tr):
     n_steps = len(cfgs) * int(np.asarray(tr.t_issue).size)
     reps = 1 if common.IS_QUICK else 3
     out, rate, jits = {}, {}, {}
-    for variant in ("dense", "fused"):
+    for body, sweep in (("dense", _sweep_dense), ("fused", dram.run_sweep)):
         j0 = dram.jit_trace_count()
-        out[variant] = jax.block_until_ready(
-            dram.run_sweep(tr, static, batch, variant=variant))  # warm/compile
-        jits[variant] = dram.jit_trace_count() - j0
+        out[body] = jax.block_until_ready(
+            sweep(tr, static, batch))                    # warm/compile
+        jits[body] = dram.jit_trace_count() - j0
         t0 = time.time()
         for _ in range(reps):
-            jax.block_until_ready(
-                dram.run_sweep(tr, static, batch, variant=variant))
-        rate[variant] = n_steps * reps / (time.time() - t0)
+            jax.block_until_ready(sweep(tr, static, batch))
+        rate[body] = n_steps * reps / (time.time() - t0)
     _assert_counters_equal(out["dense"], out["fused"], "hotloop")
     speedup = rate["fused"] / rate["dense"]
     # the DESIGN.md §9 acceptance bar is >= 2x; under --quick CI (one rep,

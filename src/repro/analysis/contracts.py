@@ -116,7 +116,7 @@ def _grid_jits(grid_kw) -> int:
 @contract("sweep.timings",
           "insert_threshold x benefit_bits grid batches into one compiled "
           "scan (pure MechParams knobs)", 1,
-          ("StaticConfig", "variant", "trace/batch shapes"))
+          ("StaticConfig", "trace/batch shapes"))
 def _c_timings() -> int:
     return _grid_jits(TIMINGS_GRID)
 
@@ -124,7 +124,7 @@ def _c_timings() -> int:
 @contract("sweep.capacity",
           "fig 12 cache-capacity grid (cache_rows 2..64) shares one padded "
           "FTS structure: one compiled scan for the whole grid", 1,
-          ("StaticConfig", "variant", "trace/batch shapes"))
+          ("StaticConfig", "trace/batch shapes"))
 def _c_capacity() -> int:
     return _grid_jits(CAPACITY_GRID)
 
@@ -132,7 +132,7 @@ def _c_capacity() -> int:
 @contract("sweep.segment",
           "fig 13 segment-size grid (seg_blocks 8..128) shares one padded "
           "FTS structure: one compiled scan for the whole grid", 1,
-          ("StaticConfig", "variant", "trace/batch shapes"))
+          ("StaticConfig", "trace/batch shapes"))
 def _c_segment() -> int:
     return _grid_jits(SEGMENT_GRID)
 
@@ -140,7 +140,7 @@ def _c_segment() -> int:
 @contract("sweep.warm-cache",
           "re-dispatching an already-compiled grid costs zero fresh "
           "compilations: traced MechParams values are NOT recompile keys",
-          0, ("StaticConfig", "variant", "trace/batch shapes"))
+          0, ("StaticConfig", "trace/batch shapes"))
 def _c_warm() -> int:
     _grid_jits(CAPACITY_GRID)          # warm (budgeted by sweep.capacity)
     return _grid_jits(CAPACITY_GRID)   # measured: must be pure cache hits
@@ -168,7 +168,7 @@ def _c_sweep_traces() -> int:
           "a chunked streamed replay reuses ONE compiled segment step for "
           "every chunk: SimState out is structurally SimState in, so all "
           "same-shape segments hit the same cache entry (DESIGN.md §13)", 1,
-          ("StaticConfig", "variant", "segment shape"))
+          ("StaticConfig", "segment shape"))
 def _c_chunked_replay() -> int:
     from repro.core import dram, streaming
     from repro.core.timing import paper_config
@@ -206,8 +206,7 @@ def _c_shard_sweep() -> int:
           "compiled telemetry step: the TelemetryWindows carry extension "
           "and the per-step frame outputs do not split the compilation "
           "cache across chunks or grid points (DESIGN.md §15)", 1,
-          ("StaticConfig (incl. telemetry period)", "variant",
-           "segment/batch shapes"))
+          ("StaticConfig (incl. telemetry period)", "segment/batch shapes"))
 def _c_telemetry_sweep() -> int:
     import dataclasses
     from repro.core import dram, streaming
@@ -233,8 +232,7 @@ def _c_telemetry_sweep() -> int:
           "SLO-threshold grid: slo_ns is traced (MechParams), so threshold "
           "sweeps batch instead of recompiling, and percentile extraction "
           "is pure host numpy (no extra programs)", 1,
-          ("StaticConfig (incl. telemetry period)", "variant",
-           "segment/batch shapes"))
+          ("StaticConfig (incl. telemetry period)", "segment/batch shapes"))
 def _c_tail_latency() -> int:
     import dataclasses
     import numpy as np

@@ -597,13 +597,15 @@ def _sim_carry_names() -> Tuple[str, ...]:
                              dram.init_counters()))
 
 
-def _trace_run_sweep(variant: str, channels: int = 0):
+def _trace_run_sweep(channels: int = 0, dense: bool = False):
+    """Abstract-trace ``run_sweep``'s body — ``simulate`` (or the dense
+    reference ``simulate_dense``) vmapped over a params batch."""
     from repro.core import dram
     from repro.core.timing import paper_config
     static = paper_config("figcache_fast").static
     tr = _abstract_trace(256, channels)
     pb = _abstract_params(batch=4)
-    fn = functools.partial(dram.simulate, variant=variant)
+    fn = dram.simulate_dense if dense else dram.simulate
     return jax.make_jaxpr(
         lambda t, p: jax.vmap(lambda one: fn(t, static, one))(p))(tr, pb)
 
@@ -615,9 +617,10 @@ def _abstract_sim_state(static, channels: int = 0, batch: int = 0):
     return jax.tree.map(lambda a: _sds(a.shape, a.dtype), st)
 
 
-def _trace_run_segment(variant: str, channels: int = 0, batch: int = 0):
+def _trace_run_segment(channels: int = 0, batch: int = 0, period: int = 0):
     """Abstract-trace the chunked segment step (``dram.run_segment`` /
-    ``run_sweep_segment``, DESIGN.md §13).
+    ``run_sweep_segment``, DESIGN.md §13), telemetry off (``period=0``)
+    or on (DESIGN.md §15).
 
     Unlike ``run_sweep``, the ``SimState`` carry enters as an *input*: the
     scan resumes from whatever the previous segment left.  The declared
@@ -628,21 +631,23 @@ def _trace_run_segment(variant: str, channels: int = 0, batch: int = 0):
     clamp is part of the step), so the pre-clamp add is bounded by
     ``LAT_SUM_CAP + T_MAX == INT32_MAX`` on EVERY segment, not just the
     first.  The carry audit checks exactly that (clamp floor + one
-    declared step of pre-clamp headroom)."""
+    declared step of pre-clamp headroom).  With telemetry on, the
+    ``_TelScan`` extension is audited against ``TEL_CARRY_BOUNDS``: the
+    packed scalar lane's clamp composes across segments exactly like
+    ``lat_sum_ns``."""
     from repro.core import dram
     from repro.core.timing import paper_config
-    static = paper_config("figcache_fast").static
+    static = dataclasses.replace(paper_config("figcache_fast"),
+                                 telemetry=period).static
     tr = _abstract_trace(256, channels)
     st = _abstract_sim_state(static, channels, batch)
     if batch:
         pb = _abstract_params(batch=batch)
         return jax.make_jaxpr(
-            lambda t, p, s: dram.sweep_resume(t, static, p, s,
-                                              variant=variant))(tr, pb, st)
+            lambda t, p, s: dram.sweep_resume(t, static, p, s))(tr, pb, st)
     p = _abstract_params()
     return jax.make_jaxpr(
-        lambda t, pp, s: dram.resume(t, static, pp, s,
-                                     variant=variant))(tr, p, st)
+        lambda t, pp, s: dram.resume(t, static, pp, s))(tr, p, st)
 
 
 def _tel_carry_names() -> Tuple[str, ...]:
@@ -664,32 +669,6 @@ def _tel_carry_names() -> Tuple[str, ...]:
     static = paper_config("figcache_fast").static
     return carry_leaf_names((dram.init_state(static),
                              dram.init_counters(), scan))
-
-
-def _trace_run_segment_tel(channels: int = 0, batch: int = 0,
-                           period: int = 64):
-    """Abstract-trace the telemetry segment step (``dram.run_segment_tel``
-    / ``run_sweep_segment_tel``, DESIGN.md §15).
-
-    Same resume-from-input carry story as ``_trace_run_segment`` — every
-    declared bound is a per-segment invariant — with the ``_TelScan``
-    extension audited against ``TEL_CARRY_BOUNDS``: the packed scalar
-    lane's clamp composes across segments exactly like ``lat_sum_ns``
-    (carried-in cursor <= LAT_SUM_CAP, pre-clamp add <= INT32_MAX)."""
-    from repro.core import dram
-    from repro.core.timing import paper_config
-    static = dataclasses.replace(paper_config("figcache_fast"),
-                                 telemetry=period).static
-    tr = _abstract_trace(256, channels)
-    st = _abstract_sim_state(static, channels, batch)
-    if batch:
-        pb = _abstract_params(batch=batch)
-        return jax.make_jaxpr(
-            lambda t, p, s: dram.sweep_resume_tel(t, static, p,
-                                                  s))(tr, pb, st)
-    p = _abstract_params()
-    return jax.make_jaxpr(
-        lambda t, pp, s: dram.resume_tel(t, static, pp, s))(tr, p, st)
 
 
 def _trace_shard_step(channels: int = 2, batch: int = 4):
@@ -756,26 +735,26 @@ def default_entries() -> List[Entry]:
     names = _sim_carry_names()
     tel_names = _tel_carry_names()
     return [
-        Entry("dram.run_sweep[fused]",
-              lambda: _trace_run_sweep("fused"),
+        Entry("dram.run_sweep",
+              lambda: _trace_run_sweep(),
               carry_names=names, carry_bounds=SIM_CARRY_BOUNDS),
         Entry("dram.run_sweep[dense]",
-              lambda: _trace_run_sweep("dense"),
+              lambda: _trace_run_sweep(dense=True),
               carry_names=names, carry_bounds=SIM_CARRY_BOUNDS),
         Entry("simulator.sweep_traces[multi-channel]",
-              lambda: _trace_run_sweep("fused", channels=2),
+              lambda: _trace_run_sweep(channels=2),
               carry_names=names, carry_bounds=SIM_CARRY_BOUNDS),
-        Entry("dram.run_segment[fused]",
-              lambda: _trace_run_segment("fused"),
+        Entry("dram.run_segment",
+              lambda: _trace_run_segment(),
               carry_names=names, carry_bounds=SIM_CARRY_BOUNDS),
         Entry("dram.run_sweep_segment[multi-channel]",
-              lambda: _trace_run_segment("fused", channels=2, batch=4),
+              lambda: _trace_run_segment(channels=2, batch=4),
               carry_names=names, carry_bounds=SIM_CARRY_BOUNDS),
-        Entry("dram.run_segment_tel[fused]",
-              lambda: _trace_run_segment_tel(),
+        Entry("dram.run_segment[telemetry]",
+              lambda: _trace_run_segment(period=64),
               carry_names=tel_names, carry_bounds=TEL_CARRY_BOUNDS),
-        Entry("dram.run_sweep_segment_tel[multi-channel]",
-              lambda: _trace_run_segment_tel(channels=2, batch=4),
+        Entry("dram.run_sweep_segment[telemetry, multi-channel]",
+              lambda: _trace_run_segment(channels=2, batch=4, period=64),
               carry_names=tel_names, carry_bounds=TEL_CARRY_BOUNDS),
         Entry("orchestrator.shard_step[sharded]",
               lambda: _trace_shard_step(channels=2, batch=4),

@@ -31,17 +31,32 @@ prefix.  One compilation therefore serves every config sharing a static
 structure, and ``run_sweep`` vmaps the very same scan over a stacked params
 batch so a whole config grid executes as one XLA program.
 
-Hot loop (DESIGN.md §9): the default ``"fused"`` scan body performs only the
+Hot loop (DESIGN.md §9): the scan body (``make_step``) performs only the
 work the step's outcome needs — the FTS decisions reduce *carried
 aggregates* (``fts.row_sum`` / free-stack) instead of re-deriving them, and
 every state change is a per-leaf ``(bank, slot)`` scalar scatter guarded by
-value-level selects.  The pre-aggregate body survives as the ``"dense"``
-variant (whole-FTS gathers, tree-wide selects, full write-backs): it is the
-bitwise reference ``tests/test_hotloop.py`` pins the fused loop against and
-the baseline ``benchmarks/sweep_engine.py`` measures steps/sec speedup over.
-``StaticConfig.fts_kernel`` further routes the remaining max_slots-wide
-reductions (tag compare + victim argmin) through the fused Pallas
-``kernels/fts_lookup`` op (pure-JAX fallback off-TPU).
+value-level selects.  The pre-aggregate "dense" body (whole-FTS gathers,
+tree-wide selects, full write-backs) survives only behind
+``simulate_dense``: it is the bitwise reference ``tests/test_hotloop.py``
+pins the scan against and the baseline ``benchmarks/sweep_engine.py``
+measures steps/sec speedup over.  ``StaticConfig.fts_kernel`` further
+routes the remaining max_slots-wide reductions (tag compare + victim
+argmin) through the fused Pallas ``kernels/fts_lookup`` op (pure-JAX
+fallback off-TPU).
+
+Entry points, one per operation — a (T,) or (C, T) trace under one params
+point or a (P,) params batch, whole or as one segment of a chunked replay:
+
+ * ``simulate`` / ``run_channel`` — one point, whole trace → ``Counters``;
+ * ``run_sweep`` — a params batch, whole trace → ``Counters``;
+ * ``resume`` / ``run_segment`` — one point, one segment →
+   ``(SimState, TelemetryFrame | None)``;
+ * ``sweep_resume`` / ``run_sweep_segment`` — a params batch, one segment →
+   ``(SimState, TelemetryFrame | None)``.
+
+Frames are ``None`` exactly when ``static.telemetry == 0``.
+``run_channel_exact`` (unpadded FTS) and ``simulate_dense`` (the dense
+body) are the two references.
 """
 from __future__ import annotations
 
@@ -439,7 +454,7 @@ class Decision(NamedTuple):
     Everything a request's outcome needs that depends only on *its own
     bank's* state (FTS decision + write-back values, row-buffer outcome,
     relocation cost) — and NOT on the channel-shared bus/MSHR timing.
-    ``dram.make_step`` ("fused") computes a Decision and then resolves the
+    ``dram.make_step`` computes a Decision and then resolves the
     shared timing serially; the bank-wavefront scan
     (``core/sched/wavefront.py``) vmaps the SAME decision function across a
     wave of distinct-bank requests and resolves the shared timing with a
@@ -673,8 +688,7 @@ def make_decision_fn(static: StaticConfig, geom: DRAMGeometry = GEOM):
     return decide
 
 
-def make_step(static: StaticConfig, geom: DRAMGeometry = GEOM,
-              variant: str = "fused"):
+def make_step(static: StaticConfig, geom: DRAMGeometry = GEOM):
     """Build the scan body for one *static structure*.
 
     The returned ``step(params, carry, req)`` closes over the padded FTS
@@ -684,26 +698,20 @@ def make_step(static: StaticConfig, geom: DRAMGeometry = GEOM,
     compilation of the scan serves arbitrarily many configs sharing
     ``static``, capacity and segment-size sweeps included (DESIGN.md §3).
 
-    ``variant="fused"`` (default) is the surgical O(1)-update hot loop —
-    carried FTS aggregates, per-(bank, slot) scalar scatters, no-op-request
-    support, optional Pallas lookup — structured as the shared per-request
+    The body is the surgical O(1)-update hot loop — carried FTS
+    aggregates, per-(bank, slot) scalar scatters, no-op-request support,
+    optional Pallas lookup — structured as the shared per-request
     ``make_decision_fn`` (the bank-local half, also vmapped by the
     wavefront scan of ``core/sched/wavefront.py``) plus the serial
-    bus/MSHR timing resolution below.  ``variant="dense"`` is the pre-
-    aggregate reference body (whole-FTS gathers / tree selects / full
-    write-backs, no no-op support): bitwise-identical on real requests,
-    kept as the equivalence bar and benchmark baseline (DESIGN.md §9).
+    bus/MSHR timing resolution below.  ``_make_step_dense`` is the
+    pre-aggregate reference, reached only through ``simulate_dense``.
 
     The carry is ``(BankState, Counters, tel)``.  With
     ``static.telemetry`` set, ``tel`` is the window accumulators plus a
     closed-window ring buffer (``_TelScan``, DESIGN.md §15); when
     disabled it is ``None`` — an empty pytree subtree, so the scan traces
-    the exact jaxpr it did before telemetry existed.  The dense reference
-    predates telemetry and rejects it.
+    the exact jaxpr it did before telemetry existed.
     """
-    if variant == "dense":
-        return _make_step_dense(static, geom)
-    assert variant == "fused", variant
     decide = make_decision_fn(static, geom)
 
     def step(params: MechParams, carry, req):
@@ -792,13 +800,13 @@ def make_step(static: StaticConfig, geom: DRAMGeometry = GEOM,
 def _make_step_dense(static: StaticConfig, geom: DRAMGeometry = GEOM):
     """The pre-aggregate scan body (DESIGN.md §9 "dense"): whole-FTS bank
     gathers, tree-wide selects and full write-backs.  Bitwise-identical to
-    the fused variant on real requests (``tests/test_hotloop.py``); does NOT
-    understand ragged no-op padding.  Kept as the equivalence reference and
-    the steps/sec baseline of ``benchmarks/sweep_engine.py``."""
+    ``make_step`` on real requests (``tests/test_hotloop.py``); does NOT
+    understand ragged no-op padding.  Reached only through
+    ``simulate_dense``."""
     if static.telemetry:
         raise ValueError(
             "telemetry windows require the fused scan body; the dense "
-            "reference predates them (set telemetry=0 or variant='fused')")
+            "reference predates them (set telemetry=0)")
     cache_base = jnp.int32(geom.n_rows)           # id-space for cache rows
     reserved_sub = geom.n_subarrays - 1           # figcache_slow region
     lisa = static.mechanism == "lisa_villa"
@@ -861,7 +869,7 @@ def _make_step_dense(static: StaticConfig, geom: DRAMGeometry = GEOM):
             do_ins = ~hit & cacheable & want
             # recompute=True: pay the seed's full-reduction insert cost
             # (free-slot argmin + segment-summed row benefits) — the dense
-            # variant is the pre-aggregate baseline AND the oracle the
+            # body is the pre-aggregate baseline AND the oracle the
             # carried aggregates are pinned against
             ins = fts_lib.insert(fts_miss, seg, req.is_write, step_id,
                                  policy=static.policy, segs_per_row=spr,
@@ -954,8 +962,8 @@ class SimState(NamedTuple):
     running a trace as sequential *segments* — ``sim_init`` once, then
     ``run_segment`` per chunk, then ``finalize`` — is bitwise identical
     to the monolithic scan for ANY chunking, provided chunk padding uses
-    the no-op sentinel (``NOOP_ISSUE``), which every step variant treats
-    as state- and counter-inert (``tests/test_streaming.py`` pins both
+    the no-op sentinel (``NOOP_ISSUE``), which the scan body treats as
+    state- and counter-inert (``tests/test_streaming.py`` pins both
     properties).  The pytree is checkpointable as-is
     (``checkpoint.save_sim_state``) so multi-million-request streamed
     replays survive preemption mid-trace.
@@ -1001,6 +1009,16 @@ def finalize(state: SimState) -> Counters:
 
 def _scan_segment(step, params: MechParams, trace: Trace, state: SimState,
                   period: int = 0):
+    """Advance ``state`` over one (T,)/(C, T) chunk: ``(SimState, frames)``.
+
+    ``frames`` is ``None`` unless ``state`` carries telemetry, in which
+    case its leaves carry the closed-window axis ``(W, ...)`` (``(C, W,
+    ...)`` for multi-channel chunks), with ``W = min(T, T // period + 2)``
+    and padding rows ``valid=False``.  Telemetry rides the carry, so
+    consuming or dropping frames never changes the counters."""
+    if trace.t_issue.ndim == 2:
+        return jax.vmap(lambda tr, st: _scan_segment(
+            step, params, tr, st, period))(trace, state)
     if state.tel is None:
         tel0 = None
     else:
@@ -1038,159 +1056,107 @@ def _scan_segment(step, params: MechParams, trace: Trace, state: SimState,
                                    tel.slo)), frames
 
 
-def _scan_one(step, params: MechParams, trace: Trace,
-              static: StaticConfig) -> Counters:
-    carry0 = SimState(init_state(static), init_counters(),
-                      init_telemetry() if static.telemetry else None)
-    return _scan_segment(step, params, trace, carry0,
-                         static.telemetry)[0].cnt
-
-
-def _resume(trace: Trace, static: StaticConfig, params: MechParams,
-            state: SimState, variant: str):
-    """Shared segment core: advance ``state`` over one (T,)/(C, T) chunk.
-
-    Returns ``(SimState, frames)``; ``frames`` is ``None`` unless
-    ``static.telemetry``, in which case its leaves carry the closed-window
-    axis ``(W, ...)`` (``(C, W, ...)`` for multi-channel chunks), with
-    ``W = min(T, T // period + 2)`` and padding rows ``valid=False``.  The
-    counters-only entry points simply drop the frames: telemetry rides the
-    carry, so consuming or dropping frames never changes the counters."""
-    step = make_step(static, variant=variant)
-    per = static.telemetry
-    if trace.t_issue.ndim == 1:
-        return _scan_segment(step, params, trace, state, per)
-    return jax.vmap(lambda tr, st: _scan_segment(step, params, tr, st, per))(
-        trace, state)
-
-
 def resume(trace: Trace, static: StaticConfig, params: MechParams,
-           state: SimState, variant: str = "fused") -> SimState:
+           state: SimState):
     """Un-jitted segment reference: one chunk of a chunked replay.
 
     ``state`` leaves must carry a leading (C,) axis iff the chunk's trace
-    leaves are (C, T).  The jitted form is ``run_segment``: every chunk
-    of the same shape reuses ONE compiled step (the fixed-shape chunks of
-    the ``traces`` codec are built for exactly this)."""
+    leaves are (C, T).  Returns ``(SimState, TelemetryFrame | None)``: the
+    segment's closed windows (DESIGN.md §15), ``None`` when
+    ``static.telemetry == 0``.  The jitted form is ``run_segment``: every
+    chunk of the same shape reuses ONE compiled step (the fixed-shape
+    chunks of the ``traces`` codec are built for exactly this)."""
     if isinstance(trace.t_issue, jax.core.Tracer):
-        _note_trace(f"segment/{static.mechanism}/{variant}")
-    return _resume(trace, static, params, state, variant)[0]
+        _note_trace(f"segment/{static.mechanism}")
+    return _scan_segment(make_step(static), params, trace, state,
+                         static.telemetry)
 
 
-def resume_tel(trace: Trace, static: StaticConfig, params: MechParams,
-               state: SimState, variant: str = "fused"):
-    """Telemetry segment: like ``resume`` but returns ``(SimState,
-    TelemetryFrame)`` so the host can collect the segment's closed
-    windows (DESIGN.md §15).  Requires ``static.telemetry > 0``; the
-    jitted form is ``run_segment_tel``."""
-    if static.telemetry <= 0:
-        raise ValueError("resume_tel needs StaticConfig.telemetry > 0 "
-                         "(the window period in real requests)")
-    if isinstance(trace.t_issue, jax.core.Tracer):
-        _note_trace(f"segment_tel/{static.mechanism}/{variant}")
-    return _resume(trace, static, params, state, variant)
+run_segment = jax.jit(resume, static_argnums=(1,))
 
 
-run_segment = jax.jit(resume, static_argnums=(1,),
-                      static_argnames=("variant",))
-run_segment_tel = jax.jit(resume_tel, static_argnums=(1,),
-                          static_argnames=("variant",))
-
-
-def simulate(trace: Trace, static: StaticConfig, params: MechParams,
-             variant: str = "fused") -> Counters:
+def simulate(trace: Trace, static: StaticConfig,
+             params: MechParams) -> Counters:
     """Un-jitted reference: one params point, (T,) or (C, T) trace leaves.
 
-    Literally ``finalize(resume(trace, ..., sim_init(...)))`` — the
+    Literally ``finalize(resume(trace, ..., sim_init(...))[0])`` — the
     monolithic scan IS the one-chunk case of the segment API, which is
     what makes chunk-size invariance structural rather than asserted."""
     if isinstance(trace.t_issue, jax.core.Tracer):
         # log only when called under a jit trace (== one compilation);
         # eager reference runs must not inflate the jit count
-        _note_trace(f"simulate/{static.mechanism}/{variant}")
+        _note_trace(f"simulate/{static.mechanism}")
     C = trace.t_issue.shape[0] if trace.t_issue.ndim == 2 else None
     state = sim_init(static, channels=C)
-    return finalize(_resume(trace, static, params, state, variant)[0])
+    return finalize(_scan_segment(make_step(static), params, trace, state,
+                                  static.telemetry)[0])
 
 
-_simulate_jit = jax.jit(simulate, static_argnums=(1,),
-                        static_argnames=("variant",))
+_simulate_jit = jax.jit(simulate, static_argnums=(1,))
+
+
+def simulate_dense(trace: Trace, static: StaticConfig,
+                   params: MechParams) -> Counters:
+    """``simulate`` through the dense reference body (DESIGN.md §9): the
+    bitwise bar ``tests/test_hotloop.py`` pins ``make_step`` against and
+    the steps/sec baseline of ``benchmarks/sweep_engine.py``.  (T,) or
+    (C, T) trace leaves of real requests only (no no-op padding); refuses
+    a telemetry static."""
+    if isinstance(trace.t_issue, jax.core.Tracer):
+        _note_trace(f"simulate_dense/{static.mechanism}")
+    step = _make_step_dense(static)
+    C = trace.t_issue.shape[0] if trace.t_issue.ndim == 2 else None
+    state = sim_init(static, channels=C)
+    return finalize(_scan_segment(step, params, trace, state)[0])
 
 
 def _sweep_resume(trace: Trace, static: StaticConfig,
-                  params_batch: MechParams, state: SimState,
-                  variant: str):
+                  params_batch: MechParams, state: SimState):
     """Shared batched-segment core: params leaves (P,), state leaves
     (P, ...) or (P, C, ...).  Returns ``(SimState, frames)`` with frame
     leaves ``(P, [C,] W, ...)`` when telemetry is on, else ``None``."""
-    step = make_step(static, variant=variant)
-    per = static.telemetry
-    if trace.t_issue.ndim == 1:
-        one = lambda p, st: _scan_segment(step, p, trace, st, per)
-    else:
-        one = lambda p, st: jax.vmap(
-            lambda tr, s: _scan_segment(step, p, tr, s, per))(trace, st)
-    return jax.vmap(one)(params_batch, state)
+    step = make_step(static)
+    return jax.vmap(lambda p, st: _scan_segment(
+        step, p, trace, st, static.telemetry))(params_batch, state)
 
 
 def sweep_resume(trace: Trace, static: StaticConfig,
-                 params_batch: MechParams, state: SimState,
-                 variant: str = "fused") -> SimState:
+                 params_batch: MechParams, state: SimState):
     """Un-jitted batched segment: ``run_sweep``'s one-chunk body, resumed
     from ``state`` (leading (P,) axes from ``sim_init(..., batch=P)``).
-    The jitted form is ``run_sweep_segment``."""
+    Returns ``(SimState, TelemetryFrame | None)`` like ``resume``, frame
+    leaves gaining the (P, [C,]) lead axes — the whole grid's window
+    series in one compiled scan.  The jitted form is
+    ``run_sweep_segment``."""
     if isinstance(trace.t_issue, jax.core.Tracer):
-        _note_trace(f"sweep_segment/{static.mechanism}/{variant}")
-    return _sweep_resume(trace, static, params_batch, state, variant)[0]
+        _note_trace(f"sweep_segment/{static.mechanism}")
+    return _sweep_resume(trace, static, params_batch, state)
 
 
-def sweep_resume_tel(trace: Trace, static: StaticConfig,
-                     params_batch: MechParams, state: SimState,
-                     variant: str = "fused"):
-    """Telemetry batched segment: ``sweep_resume`` returning the frames
-    too — the whole capacity grid's window series in one compiled scan
-    (DESIGN.md §15).  The jitted form is ``run_sweep_segment_tel``."""
-    if static.telemetry <= 0:
-        raise ValueError("sweep_resume_tel needs StaticConfig.telemetry > 0 "
-                         "(the window period in real requests)")
-    if isinstance(trace.t_issue, jax.core.Tracer):
-        _note_trace(f"sweep_segment_tel/{static.mechanism}/{variant}")
-    return _sweep_resume(trace, static, params_batch, state, variant)
+run_sweep_segment = jax.jit(sweep_resume, static_argnums=(1,))
 
 
-run_sweep_segment = jax.jit(sweep_resume, static_argnums=(1,),
-                            static_argnames=("variant",))
-run_sweep_segment_tel = jax.jit(sweep_resume_tel, static_argnums=(1,),
-                                static_argnames=("variant",))
-
-
-@functools.partial(jax.jit, static_argnums=(1,), static_argnames=("variant",))
+@functools.partial(jax.jit, static_argnums=(1,))
 def run_sweep(trace: Trace, static: StaticConfig,
-              params_batch: MechParams, variant: str = "fused") -> Counters:
+              params_batch: MechParams) -> Counters:
     """Run a whole config grid sharing one static structure in ONE program.
 
     ``params_batch`` leaves carry a leading batch axis (P,).  Returns
     ``Counters`` with leading (P,) — or (P, C) for multi-channel traces —
     bitwise-equal to running each params point through ``run_channel``.
     """
-    _note_trace(f"sweep/{static.mechanism}/{variant}")
+    _note_trace(f"sweep/{static.mechanism}")
     C = trace.t_issue.shape[0] if trace.t_issue.ndim == 2 else None
     P = jax.tree.leaves(params_batch)[0].shape[0]
     state = sim_init(static, channels=C, batch=P)
-    return finalize(_sweep_resume(trace, static, params_batch, state,
-                                  variant)[0])
+    return finalize(_sweep_resume(trace, static, params_batch, state)[0])
 
 
 def run_channel(trace: Trace, cfg: MechConfig,
                 t: DRAMTimings = DDR4) -> Counters:
-    """Simulate one channel's request stream ((T,) trace leaves)."""
+    """Simulate one channel ((T,) trace leaves) or C independent channels
+    ((C, T) leaves) under one config."""
     return _simulate_jit(trace, cfg.static, cfg.params(t))
-
-
-def run_channels(traces: Trace, cfg: MechConfig,
-                 t: DRAMTimings = DDR4) -> Counters:
-    """Simulate C independent channels: traces leaves shaped (C, T)."""
-    return _simulate_jit(traces, cfg.static, cfg.params(t))
 
 
 def run_channel_exact(trace: Trace, cfg: MechConfig,

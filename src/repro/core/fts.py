@@ -241,7 +241,7 @@ def _pick_victim_row_benefit_recompute(fts: FTS, segs_per_row, n_slots):
     """Pre-aggregate RowBenefit reference: re-derive the per-row sums from
     ``benefit`` with two segment-sum scatters over ``max_slots`` every call
     (the seed implementation).  Kept as the recompute oracle the carried
-    ``row_sum`` is pinned against — the dense scan variant and the
+    ``row_sum`` is pinned against — the dense scan body and the
     ``tests/test_hotloop.py`` property tests run THIS path and must match
     the O(1)-update path bit for bit."""
     max_slots = fts.benefit.shape[0]
@@ -316,8 +316,8 @@ def insert(fts: FTS, seg: jax.Array, is_write: jax.Array, step: jax.Array,
     hot-loop cost) instead of reading the carried aggregates; the
     aggregates are still *maintained* (the free stack is reordered so the
     argmin-chosen slot is the one popped).  It is the oracle the O(1)
-    path is pinned against (DESIGN.md §9) and the ``dense`` scan
-    variant's cost model.  Decision-equal to the O(1) path while the
+    path is pinned against (DESIGN.md §9) and the dense scan
+    body's cost model.  Decision-equal to the O(1) path while the
     store's free set is a suffix of the slot range (always true without
     ``invalidate``; after out-of-order invalidations the recompute path
     refills lowest-index-first while the stack refills

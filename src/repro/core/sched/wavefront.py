@@ -462,6 +462,6 @@ def run_channel_waves(trace: dram.Trace, cfg: MechConfig,
                       t: DRAMTimings = DDR4,
                       width: int | None = None) -> dram.Counters:
     """Convenience: form waves for ``trace`` and simulate one config —
-    the wavefront analogue of ``dram.run_channel`` / ``run_channels``."""
+    the wavefront analogue of ``dram.run_channel``."""
     wtr = form_waves(trace, width=width)
     return _simulate_waves_jit(wtr, cfg.static, cfg.params(t))

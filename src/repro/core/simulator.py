@@ -173,9 +173,9 @@ def _result_from_counters(cnt, cfg: MechConfig, apps: Sequence,
 def run_mechanism(trace: dram.Trace, cfg: MechConfig,
                   apps: Sequence[traces.AppParams]) -> RunResult:
     trace = sched_policies.schedule(trace, cfg.sched)
-    multi = np.asarray(trace.t_issue).ndim == 2
-    cnt = dram.run_channels(trace, cfg) if multi else dram.run_channel(trace, cfg)
-    n_channels = np.asarray(trace.t_issue).shape[0] if multi else 1
+    cnt = dram.run_channel(trace, cfg)
+    shape = np.shape(trace.t_issue)
+    n_channels = shape[0] if len(shape) == 2 else 1
     return _result_from_counters(cnt, cfg, apps, n_channels)
 
 
@@ -284,7 +284,7 @@ def sweep_traces(trs: Sequence, cfgs: Sequence[MechConfig],
 
     Padding no-ops are a *suffix* here only by convention — interior
     no-ops (e.g. the chunk-tail fillers a codec-decoded stream carries)
-    are equally counter-inert in every scan variant
+    are equally counter-inert in every scan body
     (``tests/test_streaming.py`` pins this), and ``chunk_len`` streams
     the stacked workloads through the segment-carried scan exactly like
     ``sweep``'s.

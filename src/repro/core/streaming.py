@@ -30,12 +30,12 @@ Pipeline, per stream:
    the already-simulated prefix.
 
 Telemetry rides the same carry: with ``cfg.telemetry > 0`` and a
-``telemetry=`` collector, segments run through ``run_segment_tel`` /
-``run_sweep_segment_tel`` and the §15 window series — including the §16
-per-window latency-histogram rows and the cumulative histogram / SLO
-planes in ``SimState.tel`` — is chunk-invariant by the same argument:
-windows are indexed by the cumulative REAL-request count, which no
-chunking or no-op padding can move.
+``telemetry=`` collector, the frames ``run_segment`` /
+``run_sweep_segment`` return are handed to it, and the §15 window series
+— including the §16 per-window latency-histogram rows and the cumulative
+histogram / SLO planes in ``SimState.tel`` — is chunk-invariant by the
+same argument: windows are indexed by the cumulative REAL-request count,
+which no chunking or no-op padding can move.
 """
 from __future__ import annotations
 
@@ -200,7 +200,7 @@ def _check_telemetry(telemetry, static, wavefront_exec=False):
 
 
 def simulate_stream(segments: Iterable[dram.Trace], cfg: MechConfig,
-                    t: DRAMTimings = DDR4, *, variant: str = "fused",
+                    t: DRAMTimings = DDR4, *,
                     wavefront_exec: bool = False,
                     state: Optional[dram.SimState] = None,
                     start_chunk: int = 0,
@@ -209,7 +209,7 @@ def simulate_stream(segments: Iterable[dram.Trace], cfg: MechConfig,
                     telemetry=None) -> dram.Counters:
     """Replay a segment stream under one config; returns final counters.
 
-    Bitwise-equal to the monolithic ``dram.run_channel(s)`` on the
+    Bitwise-equal to the monolithic ``dram.run_channel`` on the
     concatenated stream (after ``cfg.sched`` scheduling, applied here via
     the carried ``scheduled_segments`` window).  ``wavefront_exec`` forms
     per-chunk waves and drives ``wavefront.run_segment_waves`` instead of
@@ -219,10 +219,10 @@ def simulate_stream(segments: Iterable[dram.Trace], cfg: MechConfig,
 
     ``telemetry`` is a window-frame collector (``obs.WindowCollector`` —
     anything with ``add(frames)``/``close(state)``) and requires
-    ``cfg.telemetry > 0``: segments then run through ``run_segment_tel``
-    and each segment's frames are handed to the collector; because the
-    cursor rides in ``SimState.tel``, the collected series is chunking-
-    invariant (DESIGN.md §15)."""
+    ``cfg.telemetry > 0``: each segment's frames (from ``run_segment``)
+    are handed to the collector; because the cursor rides in
+    ``SimState.tel``, the collected series is chunking-invariant
+    (DESIGN.md §15)."""
     params = cfg.params(t)
     static = cfg.static
     _check_telemetry(telemetry, static, wavefront_exec)
@@ -241,13 +241,10 @@ def simulate_stream(segments: Iterable[dram.Trace], cfg: MechConfig,
             w = wavefront.pad_waves(
                 w, _wave_bucket(np.asarray(w.t_issue).shape[-2]))
             state = wavefront.run_segment_waves(w, static, params, state)
-        elif telemetry is not None:
-            state, frames = dram.run_segment_tel(seg, static, params, state,
-                                                 variant=variant)
-            telemetry.add(frames)
         else:
-            state = dram.run_segment(seg, static, params, state,
-                                     variant=variant)
+            state, frames = dram.run_segment(seg, static, params, state)
+            if telemetry is not None:
+                telemetry.add(frames)
         if checkpoint_dir and checkpoint_every and \
                 (i + 1) % checkpoint_every == 0:
             ckpt_lib.save_sim_state(checkpoint_dir, i + 1, state)
@@ -280,7 +277,7 @@ def resume_stream(segments: Iterable[dram.Trace], cfg: MechConfig,
 
 
 def sweep_stream(segments: Iterable[dram.Trace],
-                 static, params_batch, *, variant: str = "fused",
+                 static, params_batch, *,
                  state: Optional[dram.SimState] = None,
                  start_chunk: int = 0,
                  checkpoint_dir: Optional[str] = None,
@@ -296,7 +293,7 @@ def sweep_stream(segments: Iterable[dram.Trace],
     same substrate, so a killed sweep resumes mid-trace (the orchestrator,
     DESIGN.md §14, layers shard-level durability on top of this).
     ``telemetry`` collects the whole grid's window frames (leaves gain the
-    (P, [C,]) lead axes) via ``run_sweep_segment_tel`` — see
+    (P, [C,]) lead axes) from ``run_sweep_segment`` — see
     ``simulate_stream``."""
     _check_telemetry(telemetry, static)
     P = jax.tree.leaves(params_batch)[0].shape[0]
@@ -307,13 +304,10 @@ def sweep_stream(segments: Iterable[dram.Trace],
             sh = np.asarray(seg.t_issue).shape
             state = dram.sim_init(static, batch=P,
                                   channels=sh[0] if len(sh) == 2 else None)
+        state, frames = dram.run_sweep_segment(seg, static, params_batch,
+                                               state)
         if telemetry is not None:
-            state, frames = dram.run_sweep_segment_tel(
-                seg, static, params_batch, state, variant=variant)
             telemetry.add(frames)
-        else:
-            state = dram.run_sweep_segment(seg, static, params_batch, state,
-                                           variant=variant)
         if checkpoint_dir and checkpoint_every and \
                 (i + 1) % checkpoint_every == 0:
             ckpt_lib.save_sim_state(checkpoint_dir, i + 1, state)

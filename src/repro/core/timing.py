@@ -216,9 +216,11 @@ class StaticConfig:
     fts_kernel: bool = False
     # in-scan telemetry window period in REAL requests (DESIGN.md §15);
     # 0 disables.  Static because enabling it extends the scan carry with
-    # the ``dram.TelemetryWindows`` accumulators and adds per-step frame
-    # outputs — a different program structure.  Disabled (the default) is
-    # bitwise-identical to the pre-telemetry scan.
+    # the ``dram.TelemetryWindows`` accumulators and makes the segment
+    # entry points (``dram.run_segment`` / ``run_sweep_segment``) return
+    # closed-window frames instead of ``None`` — a different program
+    # structure.  Disabled (the default) is bitwise-identical to the
+    # pre-telemetry scan.
     telemetry: int = 0
 
     @property

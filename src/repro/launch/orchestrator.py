@@ -102,18 +102,17 @@ def init_progress(static, batch: int, channels: Optional[int]) -> ShardProgress:
 
 
 def shard_step(seg: dram.Trace, static, params_batch,
-               prog: ShardProgress, variant: str = "fused") -> ShardProgress:
+               prog: ShardProgress) -> ShardProgress:
     """Un-jitted single-segment shard advance (= ``dram.sweep_resume`` plus
     progress accounting).  The jitted form is ``shard_segment``; this form
     is what ``jaxpr_audit`` traces abstractly."""
-    sim = dram.sweep_resume(seg, static, params_batch, prog.sim, variant)
+    sim, _ = dram.sweep_resume(seg, static, params_batch, prog.sim)
     real = jnp.sum((seg.t_issue < dram.NOOP_ISSUE).astype(jnp.int32))
     return ShardProgress(sim=sim, seg_done=prog.seg_done + jnp.int32(1),
                          reqs_done=prog.reqs_done + real)
 
 
-shard_segment = jax.jit(shard_step, static_argnums=(1,),
-                        static_argnames=("variant",))
+shard_segment = jax.jit(shard_step, static_argnums=(1,))
 
 
 # ---------------------------------------------------------------------------

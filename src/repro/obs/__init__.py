@@ -3,10 +3,9 @@
 Two layers over the simulator and its orchestration:
 
  * ``obs.telemetry`` — host-side collection of the in-scan telemetry
-   window frames emitted by telemetry-enabled scans
-   (``dram.run_segment_tel`` / ``run_sweep_segment_tel``, enabled via
-   ``StaticConfig.telemetry``): ``WindowCollector`` masks the per-step
-   frames down to closed windows and serves per-window time series
+   window frames that ``dram.run_segment`` / ``run_sweep_segment`` return
+   when ``StaticConfig.telemetry`` is set: ``WindowCollector`` masks the
+   per-step frames down to closed windows and serves per-window time series
    (hit rates, relocation bursts, bus/MSHR stalls, per-bank issue mix).
  * ``obs.trace`` — program spans (``span``, on the profiler's clock:
    ``repro.sched.schedule``, ``repro.sweep.*``, ``repro.orch.*``) and a

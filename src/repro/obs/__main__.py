@@ -3,11 +3,11 @@
 Four sections, written into ``BENCH_obs.json`` (plus CSV/figure files):
 
  1. **Telemetry tax** on the fig12 capacity grid: the identical chunked
-    capacity sweep with telemetry off (``run_sweep_segment``) vs on with
-    frames actually collected and fenced (``run_sweep_segment_tel`` +
-    collector + ``block()`` — the full cost a telemetry consumer pays).
-    Since §16 the on-path includes the latency-histogram planes and the
-    over-SLO accounting; CI trips if the combined tax > 1.25x.
+    capacity sweep through ``run_sweep_segment`` with telemetry off vs on
+    with frames actually collected and fenced (collector + ``block()`` —
+    the full cost a telemetry consumer pays).  Since §16 the on-path
+    includes the latency-histogram planes and the over-SLO accounting; CI
+    trips if the combined tax > 1.25x.
  2. **Chunked-vs-monolithic pin**: the window series of the same grid
     replayed at chunk 64 and as one monolithic segment must be byte-equal
     for every grid point (the §13 invariance, extended to telemetry —

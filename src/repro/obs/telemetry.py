@@ -37,7 +37,7 @@ class WindowCollector:
         s = col.series()          # {"win_idx": ..., "w_cache_hits": ...,
                                   #  "hit_rate": ..., ...}
 
-    or feed ``dram.run_segment_tel`` outputs directly (``add`` per
+    or feed the frames ``dram.run_segment`` returns directly (``add`` per
     segment, ``close(state)`` once at the end).  For batched/multi-channel
     runs the frames carry lead axes (P, [C,]); pass the lead index to
     ``series`` to select one stream, e.g. ``series(index=(p, c))``.

@@ -347,17 +347,19 @@ def test_sarif_and_json_render():
 # ---------------------------------------------------------------------------
 # the lat_sum_ns saturation fix: bitwise-pinned regression
 
+# The plain reference model (bench/reference/dram.py) yields these same
+# counters, field for field, on the same trace.
 _GOLD = {
     "figcache_fast": dict(
-        acts_slow=56, acts_fast=0, reads=195, writes=61, reloc_blocks=896,
-        wb_blocks=0, row_hits=200, cache_hits=200, insertions=56,
-        lat_sum_ns=[6712, 5450, 0, 0, 0, 0, 0, 0],
-        req_cnt=[144, 112, 0, 0, 0, 0, 0, 0], t_end=30371),
+        acts_slow=53, acts_fast=0, reads=185, writes=71, reloc_blocks=848,
+        wb_blocks=0, row_hits=203, cache_hits=203, insertions=53,
+        lat_sum_ns=[6076, 4831, 0, 0, 0, 0, 0, 0],
+        req_cnt=[142, 114, 0, 0, 0, 0, 0, 0], t_end=34140),
     "base": dict(
-        acts_slow=102, acts_fast=0, reads=195, writes=61, reloc_blocks=0,
-        wb_blocks=0, row_hits=154, cache_hits=0, insertions=0,
-        lat_sum_ns=[7864, 6370, 0, 0, 0, 0, 0, 0],
-        req_cnt=[144, 112, 0, 0, 0, 0, 0, 0], t_end=30371),
+        acts_slow=90, acts_fast=0, reads=185, writes=71, reloc_blocks=0,
+        wb_blocks=0, row_hits=166, cache_hits=0, insertions=0,
+        lat_sum_ns=[7546, 5677, 0, 0, 0, 0, 0, 0],
+        req_cnt=[142, 114, 0, 0, 0, 0, 0, 0], t_end=34344),
 }
 
 

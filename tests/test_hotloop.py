@@ -9,9 +9,9 @@ Three contracts introduced by the incremental hot loop:
     the O(1) decision path must reproduce the recompute decision path
     (``insert(recompute=True)``) event for event.
  2. **Fused scan == dense scan == unpadded exact scan.**  The surgical
-    per-(bank, slot) step (``dram.make_step`` "fused", the default) must be
-    bitwise-equal to the pre-aggregate "dense" reference body and to
-    ``dram.run_channel_exact`` across all six mechanisms and all four
+    per-(bank, slot) step (``dram.make_step``) must be bitwise-equal to
+    the pre-aggregate "dense" reference body (``dram.simulate_dense``) and
+    to ``dram.run_channel_exact`` across all six mechanisms and all four
     replacement policies; the Pallas-lookup static (``fts_kernel=True``,
     pure-JAX fallback on CPU CI) must change nothing.
  3. **No-op requests are inert.**  Ragged ``sweep_traces`` pads unequal
@@ -179,7 +179,7 @@ def test_fused_step_bitwise_all_mechanisms_policies(mech, policy):
     cfg = paper_config(mech, cache_rows=2, policy=policy) \
         if mech in CACHED else paper_config(mech, policy=policy)
     fused = dram.run_channel(tr, cfg)
-    dense = dram._simulate_jit(tr, cfg.static, cfg.params(), variant="dense")
+    dense = dram.simulate_dense(tr, cfg.static, cfg.params())
     exact = dram.run_channel_exact(tr, cfg)
     _assert_counters_equal(fused, dense, (mech, policy, "dense"))
     _assert_counters_equal(fused, exact, (mech, policy, "exact"))

@@ -292,13 +292,46 @@ def test_telemetry_guardrails():
         streaming.simulate_stream(streaming.iter_chunks(tr, 160), cfg_tel,
                                   telemetry=WindowCollector(),
                                   wavefront_exec=True)
-    # the dense research variant rejects telemetry instead of lying
+    # the dense reference body rejects telemetry instead of lying
     with pytest.raises(ValueError, match="dense"):
-        dram.simulate(tr, cfg_tel.static, cfg_tel.params(), variant="dense")
-    # the telemetry entry points refuse a disabled static
-    with pytest.raises(ValueError, match="telemetry"):
-        dram.resume_tel(tr, cfg_off.static, cfg_off.params(),
-                        dram.sim_init(cfg_off.static))
+        dram.simulate_dense(tr, cfg_tel.static, cfg_tel.params())
+    # a disabled static yields no frames, not empty ones
+    _, frames = dram.resume(tr, cfg_off.static, cfg_off.params(),
+                            dram.sim_init(cfg_off.static))
+    assert frames is None
+
+
+@pytest.mark.parametrize("tel", [0, PERIOD], ids=["off", "on"])
+@pytest.mark.parametrize("batched", [False, True],
+                         ids=["run_segment", "run_sweep_segment"])
+def test_segment_entry_points_frames_and_one_chunk(batched, tel):
+    """The segment entry points return ``(SimState, frames)``: frames are
+    ``None`` exactly when telemetry is off, and a one-chunk replay's state
+    equals the monolithic ``simulate`` / ``run_sweep`` bitwise."""
+    tr = _reuse_trace()
+    if batched:
+        cfgs = [dataclasses.replace(paper_config("figcache_fast",
+                                                 cache_rows=cr),
+                                    telemetry=tel) for cr in (2, 64)]
+        static = shared_static(cfgs)
+        pb = jax.tree.map(lambda *xs: jnp.stack(xs),
+                          *[c.params() for c in cfgs])
+        state, frames = dram.run_sweep_segment(
+            tr, static, pb, dram.sim_init(static, batch=len(cfgs)))
+        ref = dram.run_sweep(tr, static, pb)
+    else:
+        cfg = dataclasses.replace(_cfg("figcache_fast"), telemetry=tel)
+        state, frames = dram.run_segment(tr, cfg.static, cfg.params(),
+                                         dram.sim_init(cfg.static))
+        ref = dram.simulate(tr, cfg.static, cfg.params())
+    if tel:
+        assert frames is not None and state.tel is not None
+        valid = np.asarray(frames.valid)
+        assert valid.shape[:-1] == ((2,) if batched else ())
+        assert valid.any(axis=-1).all()
+    else:
+        assert frames is None and state.tel is None
+    _assert_counters_equal(ref, dram.finalize(state), (batched, tel))
 
 
 def test_window_table_and_csv_render():

@@ -117,7 +117,7 @@ def test_wavefront_bitwise_multi_channel():
     apps = tuple(traces.app_params(n) for n in ("libquantum", "mcf"))
     tr = traces.build_trace(list(apps), 2, 512, 4)
     cfg = paper_config("figcache_fast", cache_rows=4)
-    _assert_counters_equal(dram.run_channels(tr, cfg),
+    _assert_counters_equal(dram.run_channel(tr, cfg),
                            sched.run_channel_waves(tr, cfg), "multi")
 
 

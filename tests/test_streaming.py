@@ -156,7 +156,7 @@ def test_chunk_invariance_multi_channel():
     apps = tuple(traces.app_params(n) for n in ("libquantum", "mcf"))
     tr = traces.build_trace(list(apps), 2, 512, 4)
     cfg = _cfg("figcache_fast")
-    mono = dram.run_channels(tr, cfg)
+    mono = dram.run_channel(tr, cfg)
     for L in (96, 512):
         got = streaming.simulate_stream(streaming.iter_chunks(tr, L), cfg)
         _assert_counters_equal(mono, got, ("multi", L))
@@ -167,7 +167,7 @@ def test_chunk_invariance_multi_channel_scheduled():
     tr = traces.build_trace(list(apps), 2, 384, 4)
     sc = SCHEDS[3]
     cfg = _cfg("figcache_fast", sched=sc)
-    mono = dram.run_channels(policies.schedule(tr, sc), cfg)
+    mono = dram.run_channel(policies.schedule(tr, sc), cfg)
     got = streaming.simulate_stream(streaming.iter_chunks(tr, 100), cfg)
     _assert_counters_equal(mono, got, "multi-sched")
 
@@ -305,7 +305,7 @@ def test_codec_segments_replay_bitwise():
         jax.tree.map(lambda a, c=c: np.asarray(a)[c], mtr), chunk_len=64)
         for c in range(2)]
     _assert_counters_equal(
-        dram.run_channels(mtr, cfg),
+        dram.run_channel(mtr, cfg),
         streaming.simulate_stream(streaming.decoded_segments(enc2), cfg),
         "codec-replay-multi")
 
@@ -444,6 +444,6 @@ def test_generate_stream_replays_bitwise():
         lambda x, y: jnp.concatenate(
             [jnp.asarray(x), jnp.asarray(y)], axis=-1), *segs)
     cfg = _cfg("figcache_fast")
-    _assert_counters_equal(dram.run_channels(cat, cfg),
+    _assert_counters_equal(dram.run_channel(cat, cfg),
                            streaming.simulate_stream(iter(segs), cfg),
                            "generate-stream")

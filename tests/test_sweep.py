@@ -65,7 +65,7 @@ def test_run_sweep_multi_channel():
     swept = dram.run_sweep(tr, cfgs[0].static, batch)
     assert np.asarray(swept.reads).shape[:2] == (3, 2)   # (P, C)
     for i, cfg in enumerate(cfgs):
-        ref = dram.run_channels(tr, cfg)
+        ref = dram.run_channel(tr, cfg)
         got = jax.tree.map(lambda a, i=i: a[i], swept)
         _assert_counters_equal(ref, got, ("multi", i))
 
@@ -139,7 +139,7 @@ def test_results_are_host_numpy_and_match_run_mechanism(entry, multi):
     for w, (t, row) in enumerate(got):
         for cfg, r in zip(cfgs, row):
             ref = simulator.run_mechanism(t, cfg, apps)
-            scan = (dram.run_channels if multi else dram.run_channel)(t, cfg)
+            scan = dram.run_channel(t, cfg)
             for name, x, y, z in zip(scan._fields, scan, ref.counters,
                                      r.counters):
                 ctx = (w, cfg.mechanism, name)
