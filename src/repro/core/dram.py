@@ -34,15 +34,18 @@ batch so a whole config grid executes as one XLA program.
 Hot loop (DESIGN.md §9): the scan body (``make_step``) performs only the
 work the step's outcome needs — the FTS decisions reduce *carried
 aggregates* (``fts.row_sum`` / free-stack) instead of re-deriving them, and
-every state change is a per-leaf ``(bank, slot)`` scalar scatter guarded by
-value-level selects.  The pre-aggregate "dense" body (whole-FTS gathers,
-tree-wide selects, full write-backs) survives only behind
-``simulate_dense``: it is the bitwise reference ``tests/test_hotloop.py``
-pins the scan against and the baseline ``benchmarks/sweep_engine.py``
-measures steps/sec speedup over.  ``StaticConfig.fts_kernel`` further
-routes the remaining max_slots-wide reductions (tag compare + victim
-argmin) through the fused Pallas ``kernels/fts_lookup`` op (pure-JAX
-fallback off-TPU).
+every state change is a value-select-guarded write of one entry.  Under
+the lane vmap each gather or scatter runs its lanes' indices one after
+another on the TPU, so leaves on the bank, core and MSHR axes are read and
+written by one-hot selects (``fts.onehot_read`` / ``onehot_write``); only
+the slot-sized planes keep ``(bank, slot)`` gathers and scatters.  The
+pre-aggregate "dense" body (whole-FTS gathers, tree-wide selects, full
+write-backs) survives only behind ``simulate_dense``: it is the bitwise
+reference ``tests/test_hotloop.py`` pins the scan against and the baseline
+``benchmarks/sweep_engine.py`` measures steps/sec speedup over.
+``StaticConfig.fts_kernel`` further routes the remaining max_slots-wide
+reductions (tag compare + victim argmin) through the fused Pallas
+``kernels/fts_lookup`` op (pure-JAX fallback off-TPU).
 
 Entry points, one per operation — a (T,) or (C, T) trace under one params
 point or a (P,) params batch, whole or as one segment of a chunked replay:
@@ -67,6 +70,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import fts as fts_lib
+from repro.core.fts import onehot_read, onehot_write
 from repro.core.timing import (DDR4, GEOM, DRAMGeometry, DRAMTimings,
                                MechConfig, MechParams, StaticConfig)
 from repro.kernels.fts_lookup.ops import fts_lookup_op
@@ -514,7 +518,7 @@ def make_decision_fn(static: StaticConfig, geom: DRAMGeometry = GEOM):
         bank = req.bank
         f = state.fts
         real = req.t_issue < NOOP_ISSUE
-        open_b = state.open_row[bank]
+        open_b = onehot_read(state.open_row, bank)
 
         # ---- cache lookup + victim candidate (one pass over the bank) ----
         if static.has_cache:
@@ -524,6 +528,10 @@ def make_decision_fn(static: StaticConfig, geom: DRAMGeometry = GEOM):
             else:
                 cacheable = jnp.bool_(True)
             row_benefit = static.policy == "row_benefit"
+            if row_benefit:
+                row_sum_b = f.row_sum[bank]
+            evict_row_b = onehot_read(f.evict_row, bank)
+            evict_mask_b = onehot_read(f.evict_mask, bank)
             if static.fts_kernel:
                 # fused VMEM pass: tag compare + the policy's masked victim
                 # argmin in ONE visit of the bank's row.  Relies on the
@@ -549,7 +557,7 @@ def make_decision_fn(static: StaticConfig, geom: DRAMGeometry = GEOM):
                 slot = jnp.argmax(m).astype(jnp.int32)
                 if row_benefit:
                     rows = jnp.arange(max_slots, dtype=jnp.int32)
-                    cand = fts_lib.masked_argmin(f.row_sum[bank],
+                    cand = fts_lib.masked_argmin(row_sum_b,
                                                  rows * spr < p.n_slots)
                 elif static.policy in ("segment_benefit", "lru"):
                     arr = f.benefit if static.policy == "segment_benefit" \
@@ -563,7 +571,7 @@ def make_decision_fn(static: StaticConfig, geom: DRAMGeometry = GEOM):
             # ---- replacement decision from carried aggregates ------------
             if row_benefit:
                 row_sel, mask_sel = fts_lib.pick_victim_row(
-                    f.row_sum[bank], f.evict_row[bank], f.evict_mask[bank],
+                    row_sum_b, evict_row_b, evict_mask_b,
                     spr, p.n_slots, new_row=cand)
                 bidx = jnp.clip(row_sel * spr +
                                 jnp.arange(max_segs, dtype=jnp.int32),
@@ -574,7 +582,7 @@ def make_decision_fn(static: StaticConfig, geom: DRAMGeometry = GEOM):
                 victim_slot = fts_lib.random_victim(step_id, p.n_slots)
             else:
                 victim_slot = cand
-            n_valid_b = f.n_valid[bank]
+            n_valid_b = onehot_read(f.n_valid, bank)
             has_free = n_valid_b < p.n_slots
             free_slot = f.free_list[bank,
                                     jnp.minimum(n_valid_b, max_slots - 1)]
@@ -582,8 +590,10 @@ def make_decision_fn(static: StaticConfig, geom: DRAMGeometry = GEOM):
             # ---- insertion policy (consecutive-miss tracker) -------------
             n_track = f.miss_tags.shape[1]
             tr_idx = jnp.remainder(seg, n_track)
-            same = f.miss_tags[bank, tr_idx] == seg
-            cnt_new = jnp.where(same, f.miss_cnt[bank, tr_idx] + 1, 1)
+            old_miss_tag = f.miss_tags[bank, tr_idx]
+            old_miss_cnt = f.miss_cnt[bank, tr_idx]
+            same = old_miss_tag == seg
+            cnt_new = jnp.where(same, old_miss_cnt + 1, 1)
             want = (p.insert_threshold <= 1) | (cnt_new >= p.insert_threshold)
             # the tracker advances on actual (cacheable) misses only
             advance = real & cacheable & ~hit_raw
@@ -607,13 +617,10 @@ def make_decision_fn(static: StaticConfig, geom: DRAMGeometry = GEOM):
                                     jnp.where(hit, b_touch, old_benefit))
             use_victim = do_ins & ~has_free
             if row_benefit:
-                new_evict_row = jnp.where(use_victim, row_sel,
-                                          f.evict_row[bank])
-                new_evict_mask = jnp.where(use_victim, mask_new,
-                                           f.evict_mask[bank])
+                new_evict_row = jnp.where(use_victim, row_sel, evict_row_b)
+                new_evict_mask = jnp.where(use_victim, mask_new, evict_mask_b)
             else:
-                new_evict_row = f.evict_row[bank]
-                new_evict_mask = f.evict_mask[bank]
+                new_evict_row, new_evict_mask = evict_row_b, evict_mask_b
             write = fts_lib.SlotWrite(
                 w=w,
                 tag=jnp.where(do_ins, seg, old_tag),
@@ -626,8 +633,8 @@ def make_decision_fn(static: StaticConfig, geom: DRAMGeometry = GEOM):
                 evict_row=new_evict_row,
                 evict_mask=new_evict_mask,
                 tr_idx=tr_idx,
-                miss_tag=jnp.where(advance, seg, f.miss_tags[bank, tr_idx]),
-                miss_cnt=jnp.where(advance, cnt_new, f.miss_cnt[bank, tr_idx]),
+                miss_tag=jnp.where(advance, seg, old_miss_tag),
+                miss_cnt=jnp.where(advance, cnt_new, old_miss_cnt),
                 n_valid_inc=(do_ins & has_free).astype(jnp.int32),
             )
         else:
@@ -699,8 +706,9 @@ def make_step(static: StaticConfig, geom: DRAMGeometry = GEOM):
     ``static``, capacity and segment-size sweeps included (DESIGN.md §3).
 
     The body is the surgical O(1)-update hot loop — carried FTS
-    aggregates, per-(bank, slot) scalar scatters, no-op-request support,
-    optional Pallas lookup — structured as the shared per-request
+    aggregates, one-hot selects on the small axes, per-(bank, slot)
+    scatters on the slot planes, no-op-request support, optional Pallas
+    lookup — structured as the shared per-request
     ``make_decision_fn`` (the bank-local half, also vmapped by the
     wavefront scan of ``core/sched/wavefront.py``) plus the serial
     bus/MSHR timing resolution below.  ``_make_step_dense`` is the
@@ -726,10 +734,12 @@ def make_step(static: StaticConfig, geom: DRAMGeometry = GEOM):
         # ---- channel-shared timing: MSHR closed loop + data bus -----------
         # a core may not have more than N_MSHR requests in flight — it
         # stalls until the request N_MSHR-ago completed
-        mshr_slot = state.mshr_idx[core]
-        mshr_free = state.mshr_ring[core, mshr_slot]
+        mshr_slot = onehot_read(state.mshr_idx, core)
+        ring_c = onehot_read(state.mshr_ring, core)
+        mshr_free = onehot_read(ring_c, mshr_slot)
         t_ready = jnp.maximum(req.t_issue, mshr_free)
-        t0 = jnp.maximum(t_ready, state.busy[bank])
+        busy_b = onehot_read(state.busy, bank)
+        t0 = jnp.maximum(t_ready, busy_b)
         # the 64 B burst serializes on the shared channel data bus — a
         # contention source no in-DRAM cache can relieve
         done = jnp.maximum(t0 + dec.pre_act + p.cas, state.bus_free) + p.bl
@@ -743,22 +753,22 @@ def make_step(static: StaticConfig, geom: DRAMGeometry = GEOM):
         else:
             new_fts = state.fts
         state = BankState(
-            open_row=state.open_row.at[bank].set(
-                jnp.where(real, dec.new_open, state.open_row[bank])),
-            busy=state.busy.at[bank].set(
-                jnp.where(real, serv_end + dec.reloc_cost,
-                          state.busy[bank])),
+            open_row=onehot_write(state.open_row, bank, jnp.where(
+                real, dec.new_open, onehot_read(state.open_row, bank))),
+            busy=onehot_write(state.busy, bank, jnp.where(
+                real, serv_end + dec.reloc_cost, busy_b)),
             fts=new_fts,
-            mshr_ring=state.mshr_ring.at[core, mshr_slot].set(
-                jnp.where(real, done, mshr_free)),
-            mshr_idx=state.mshr_idx.at[core].set(
-                jnp.where(real, (mshr_slot + 1) % N_MSHR, mshr_slot)),
+            mshr_ring=onehot_write(state.mshr_ring, core, onehot_write(
+                ring_c, mshr_slot, jnp.where(real, done, mshr_free))),
+            mshr_idx=onehot_write(state.mshr_idx, core, jnp.where(
+                real, (mshr_slot + 1) % N_MSHR, mshr_slot)),
             bus_free=jnp.where(real, done, state.bus_free),
         )
 
         # ---- counters ------------------------------------------------------
         act = ((~dec.row_hit) & real).astype(jnp.int32)
         lat_ns = ((done - t_ready) // 8).astype(jnp.int32)
+        core_m = (jnp.arange(cnt.req_cnt.shape[0]) == core) & real
         cnt = Counters(
             acts_slow=cnt.acts_slow + act * (~dec.served_fast),
             acts_fast=cnt.acts_fast + act * dec.served_fast,
@@ -770,9 +780,8 @@ def make_step(static: StaticConfig, geom: DRAMGeometry = GEOM):
             cache_hits=cnt.cache_hits + dec.hit.astype(jnp.int32),
             insertions=cnt.insertions + dec.n_ins,
             lat_sum_ns=jnp.minimum(
-                cnt.lat_sum_ns.at[core].add(jnp.where(real, lat_ns, 0)),
-                LAT_SUM_CAP),
-            req_cnt=cnt.req_cnt.at[core].add(real.astype(jnp.int32)),
+                cnt.lat_sum_ns + jnp.where(core_m, lat_ns, 0), LAT_SUM_CAP),
+            req_cnt=cnt.req_cnt + core_m.astype(jnp.int32),
             # the request is not retired until its burst clears the shared
             # data bus, which can outlast the bank's own serv_end+reloc —
             # take the max over *both* (execution time feeds core/energy.py)

@@ -53,6 +53,8 @@ operation is bitwise-identical to an unpadded tag store (regression:
 entire capacity or segment-size sweep (``core/dram.py:run_sweep``).
 
 All ops are branchless (arithmetic select) so they jit/scan/vmap cleanly.
+``onehot_read`` / ``onehot_write`` are the simulator hot loop's indexing
+rule for the store's small per-bank leaves (DESIGN.md §9).
 """
 from __future__ import annotations
 
@@ -101,6 +103,46 @@ def init(max_slots: int, max_segs_per_row: int, n_track: int = 256) -> FTS:
         free_list=jnp.arange(max_slots, dtype=jnp.int32),
         n_valid=jnp.int32(0),
     )
+
+
+def _onehot(i: jax.Array, n: int, trailing: int) -> jax.Array:
+    """``i.shape + (n,) + (1,) * trailing`` bool mask, True at ``i``."""
+    m = jnp.asarray(i, jnp.int32)[..., None] == jnp.arange(n, dtype=jnp.int32)
+    return m.reshape(m.shape + (1,) * trailing)
+
+
+def onehot_read(x: jax.Array, i: jax.Array) -> jax.Array:
+    """``x[i]`` along axis 0 as a masked reduction, not a gather.
+
+    The hot loop's rule for small leaves (DESIGN.md §9): on the bank, core
+    and MSHR axes a dense compare/select over a few dozen entries costs
+    nothing per lane, where a gather costs one serial index per lane on
+    the TPU.  ``i`` is a scalar, or a vector of indices (one read each).
+    Exact: the reduction adds zeros to (or ORs False into) one entry."""
+    m = _onehot(i, x.shape[0], x.ndim - 1)
+    axis = jnp.ndim(i)
+    if x.dtype == jnp.bool_:
+        return jnp.any(m & x, axis=axis)
+    return jnp.sum(jnp.where(m, x, 0), axis=axis, dtype=x.dtype)
+
+
+def onehot_write(x: jax.Array, i: jax.Array, v: jax.Array) -> jax.Array:
+    """``x.at[i].set(v)`` along axis 0 as a one-hot select, not a scatter.
+
+    ``i`` is a scalar (``v`` shaped ``x.shape[1:]``) or a vector of
+    *distinct* indices with one row of ``v`` each — the bank wavefront's
+    write, where distinctness makes the result the scatter's."""
+    n, trailing = x.shape[0], x.ndim - 1
+    v = jnp.asarray(v, x.dtype)
+    if jnp.ndim(i) == 0:
+        return jnp.where(_onehot(i, n, trailing), v, x)
+    m = _onehot(i, n, trailing)                       # (K, n, 1...)
+    v = v.reshape((v.shape[0], 1) + x.shape[1:])      # (K, 1, ...)
+    if x.dtype == jnp.bool_:
+        picked = jnp.any(m & v, axis=0)
+    else:
+        picked = jnp.sum(jnp.where(m, v, 0), axis=0, dtype=x.dtype)
+    return jnp.where(jnp.any(m, axis=0), picked, x)
 
 
 def _active(fts: FTS, n_slots) -> jax.Array:
@@ -203,7 +245,7 @@ def pick_victim_in_row(benefit_row: jax.Array, mask: jax.Array,
     spr = jnp.asarray(segs_per_row, jnp.int32)
     j = jnp.arange(mask.shape[0], dtype=jnp.int32)
     jj = masked_argmin(benefit_row, (j < spr) & mask)
-    return row * spr + jj, mask.at[jj].set(False)
+    return row * spr + jj, mask & (j != jj)
 
 
 def gather_row(benefit: jax.Array, row: jax.Array, max_segs: int,
@@ -386,10 +428,10 @@ class SlotWrite(NamedTuple):
 
     Shapes are scalar for the serial fused scan and ``(W,)``-batched for
     the bank-wavefront scan (``core/sched/wavefront.py``): the SAME
-    ``apply_write`` serves both because ``.at[bank, w]`` indexing accepts a
-    scalar bank or a vector of *distinct* banks alike — wave formation
-    guarantees distinctness, which is what makes the vectorized scatter
-    deterministic.
+    ``apply_write`` serves both because ``.at[bank, w]`` indexing and
+    ``onehot_write`` accept a scalar bank or a vector of *distinct* banks
+    alike — wave formation guarantees distinctness, which is what makes
+    the vectorized writes deterministic.
     """
     w: jax.Array          # slot written (hit slot or insertion landing slot)
     tag: jax.Array
@@ -422,11 +464,12 @@ def apply_write(fts: FTS, bank: jax.Array, segs_per_row,
         benefit=fts.benefit.at[bank, wr.w].set(wr.benefit),
         last_use=fts.last_use.at[bank, wr.w].set(wr.last_use),
         row_sum=fts.row_sum.at[bank, wr.w // spr].add(wr.row_delta),
-        evict_row=fts.evict_row.at[bank].set(wr.evict_row),
-        evict_mask=fts.evict_mask.at[bank].set(wr.evict_mask),
+        evict_row=onehot_write(fts.evict_row, bank, wr.evict_row),
+        evict_mask=onehot_write(fts.evict_mask, bank, wr.evict_mask),
         miss_tags=fts.miss_tags.at[bank, wr.tr_idx].set(wr.miss_tag),
         miss_cnt=fts.miss_cnt.at[bank, wr.tr_idx].set(wr.miss_cnt),
-        n_valid=fts.n_valid.at[bank].add(wr.n_valid_inc),
+        n_valid=onehot_write(fts.n_valid, bank,
+                             onehot_read(fts.n_valid, bank) + wr.n_valid_inc),
     )
 
 

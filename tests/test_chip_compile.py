@@ -11,6 +11,7 @@ imported: only one process may hold the TPU library at a time, and pytest
 workers import every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -99,3 +100,45 @@ def test_run_sweep_compiles_with_fts_kernel(one_chip, monkeypatch):
     compiled = dram.run_sweep.lower(trace, static, batch).compile()
     print(compiled.memory_analysis())
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _while_index_fusions(text):
+    """Gather and scatter fusions (and bare ops) in the ``while`` bodies
+    of an optimized HLO module's text."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    bodies = re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", text)
+    assert bodies, "no while loop in the compiled scan"
+    indexed = lambda ls: any(re.search(r" (gather|scatter)\(", l) for l in ls)
+    n = 0
+    for body in bodies:
+        for line in comps[body]:
+            called = re.search(r" fusion\(.*calls=%?([\w.\-]+)", line)
+            n += indexed(comps[called.group(1)]) if called else \
+                indexed([line])
+    return n
+
+
+# per-lane index fusions of one scan trip: each runs its lanes' indices one
+# after another on the TPU (DESIGN.md §9); 10 and 34 before one-hot state
+TRIP_INDEX_FUSIONS = {"base": 0, "figcache_fast": 17}
+
+
+@pytest.mark.parametrize("mech", sorted(TRIP_INDEX_FUSIONS))
+def test_run_sweep_trip_index_fusions(one_chip, mech):
+    """The ``mechs`` cells' scan, 32 lanes x 8192 requests, compiled for a
+    v5e: the ``while`` body's gather and scatter fusions."""
+    cfg = paper_config(mech)
+    batch = jax.tree.map(lambda a: _sds((1,) + a.shape, a.dtype, one_chip),
+                         cfg.params())
+    C, T = 32, 8192
+    trace = dram.Trace(*(_sds((C, T), dt, one_chip) for dt in (
+        jnp.int32, jnp.int32, jnp.int32, jnp.int32, jnp.bool_, jnp.int32)))
+    text = dram.run_sweep.lower(trace, cfg.static, batch).compile().as_text()
+    assert _while_index_fusions(text) <= TRIP_INDEX_FUSIONS[mech]

@@ -247,3 +247,66 @@ def test_sweep_traces_channel_count_must_agree():
     with pytest.raises(AssertionError):
         simulator.sweep_traces([one, two], [paper_config("base")],
                                [(a,), (a, a)])
+
+
+# ---------------------------------------------------------------------------
+# 4. per-lane index ops (DESIGN.md §9): one-hot access to the small leaves
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.bool_])
+@pytest.mark.parametrize("i", [0, 15])
+def test_onehot_helpers_match_indexing(dtype, i):
+    """``onehot_read`` / ``onehot_write`` on a bank axis of 16 are ``x[i]``
+    and ``x.at[i].set(v)``, for scalar and row leaves, at the first and
+    the last bank."""
+    rng = np.random.default_rng(i)
+    for shape in ((16,), (16, 8)):
+        x = jnp.asarray(rng.integers(-9, 9, shape)).astype(dtype)
+        v = jnp.asarray(rng.integers(-9, 9, shape[1:])).astype(dtype)
+        idx = jnp.int32(i)
+        assert np.array_equal(fts_lib.onehot_read(x, idx), x[i])
+        assert np.array_equal(fts_lib.onehot_write(x, idx, v),
+                              x.at[i].set(v))
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.bool_])
+def test_onehot_helpers_under_vmap_with_distinct_banks(dtype):
+    """The wavefront's use: ``decide`` vmapped over a wave of distinct
+    banks reads with ``onehot_read``, and ``apply_write`` writes the whole
+    wave with one vector-index ``onehot_write``; the serial step runs
+    under the lane vmap with its state batched too."""
+    rng = np.random.default_rng(5)
+    banks = jnp.asarray([15, 0, 7, 3], jnp.int32)
+    x = jnp.asarray(rng.integers(-9, 9, (16, 8))).astype(dtype)
+    v = jnp.asarray(rng.integers(-9, 9, (4, 8))).astype(dtype)
+    got = jax.vmap(fts_lib.onehot_read, in_axes=(None, 0))(x, banks)
+    assert np.array_equal(got, x[banks])
+    assert np.array_equal(fts_lib.onehot_write(x, banks, v),
+                          x.at[banks].set(v))
+    assert np.array_equal(fts_lib.onehot_write(x[:, 0], banks, v[:, 0]),
+                          x[:, 0].at[banks].set(v[:, 0]))
+    xs = jnp.asarray(rng.integers(-9, 9, (4, 16, 8))).astype(dtype)
+    want = jnp.stack([xs[k].at[banks[k]].set(v[k]) for k in range(4)])
+    assert np.array_equal(jax.vmap(fts_lib.onehot_write)(xs, banks, v), want)
+
+
+# gather + scatter primitives in run_sweep's scan body: none on the bank,
+# core and MSHR axes (one-hot); for a caching mechanism the slot planes'
+# reads and writes at (bank, w), the tracker pair's at (bank, tr_idx), and
+# the lookup's row gathers (43 before, DESIGN.md §9)
+SCAN_INDEX_OPS = {"base": 0, "lldram": 0, **{m: 19 for m in CACHED}}
+
+
+@pytest.mark.parametrize("mech", sorted(SCAN_INDEX_OPS))
+def test_scan_body_index_ops(mech):
+    from repro.analysis.jaxpr_audit import _walk
+    cfg = paper_config(mech)
+    batch = jax.tree.map(lambda a: a[None], cfg.params())
+    tr = dram.Trace(*(jax.ShapeDtypeStruct((4, 64), dt) for dt in (
+        jnp.int32, jnp.int32, jnp.int32, jnp.int32, jnp.bool_, jnp.int32)))
+    closed = jax.make_jaxpr(
+        lambda t, b: dram.run_sweep(t, cfg.static, b))(tr, batch)
+    n = sum(1 for eqn, _path, depth in _walk(closed.jaxpr)
+            if depth >= 1 and eqn.primitive.name in
+            ("gather", "scatter", "scatter-add"))
+    assert n <= SCAN_INDEX_OPS[mech], (mech, n)
